@@ -48,10 +48,11 @@ batched-vs-python ratio, machine-normalized) must not regress by more than
 in the payload, and the process exits non-zero on a gate failure — this is
 the CI perf-trajectory gate.
 
-``--compile-cache DIR`` points JAX's persistent compilation cache at
-``DIR`` (CI keeps it under the workflow cache), so the *cold* call hits
-compiled programs on disk instead of re-lowering from scratch —
-``speedup_cold`` then measures dispatch, not compilation.  ``--stress``
+``--compile-cache`` turns on JAX's persistent compilation cache through
+:func:`repro.compile_cache.enable_compile_cache` (``JAX_COMPILATION_CACHE_DIR``
+when set — CI points it at its workflow cache — else ``<repo>/.jax_cache``),
+so the *cold* call hits compiled programs on disk instead of re-lowering
+from scratch — ``speedup_cold`` then measures dispatch, not compilation.  ``--stress``
 runs only the memory-bound chunked stress point (≥ 20k events per
 replica; CI caps ``XLA_PYTHON_CLIENT_MEM_FRACTION`` and skips the
 monolithic path, which would materialize the full event/trace tensors).
@@ -65,6 +66,7 @@ import json
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.policy import list_policies
 from repro.sim import SimConfig, run_many
 from repro.sim.batched import run_batched
@@ -80,26 +82,6 @@ QUEUED_METRIC_TOL = 1e-6
 #: warm throughput (same run, same machine — per-chunk dispatch overhead is
 #: the only legitimate cost) and match its acceptance bit-for-bit
 CHUNKED_WARM_TOL = 0.10
-
-
-def enable_compile_cache(cache_dir: str) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
-
-    Keyed into the CI workflow cache, this turns the cold call's XLA
-    compilation into a disk hit on every run after the first —
-    ``speedup_cold`` then tracks dispatch overhead instead of compile time.
-    Thresholds are zeroed so even the small smoke-point programs persist.
-    """
-    import os
-
-    import jax
-
-    cache_dir = os.path.expanduser(cache_dir)
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    return cache_dir
 
 
 def sweep_policies(cfg: SimConfig, runs: int):
@@ -626,9 +608,8 @@ def main(runs: int = 64, num_gpus: int = 100, load: float = 0.85,
          policy: str = "mfi", py_runs: int = 3, smoke: bool = False,
          json_path: str | None = None, sweep: bool | None = None,
          profile: bool = False, baseline: str | None = None,
-         compile_cache: str | None = None, stress: bool = False):
-    if compile_cache:
-        compile_cache = enable_compile_cache(compile_cache)
+         compile_cache: bool = False, stress: bool = False):
+    compile_cache = enable_compile_cache() if compile_cache else None
     if stress:  # memory-bound chunked stress point only (CI runs it under a
         # capped XLA_PYTHON_CLIENT_MEM_FRACTION; the monolithic path is
         # skipped by design at this stream length)
@@ -871,10 +852,11 @@ if __name__ == "__main__":
                     help="diff against a committed artifact (e.g. "
                          "benchmarks/BENCH_baseline.json); exits non-zero on "
                          ">20%% speedup_warm regression")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable JAX's persistent compilation cache at DIR "
-                         "(kept under the CI workflow cache so cold calls "
-                         "hit disk instead of recompiling)")
+    ap.add_argument("--compile-cache", action="store_true",
+                    help="enable JAX's persistent compilation cache "
+                         "(JAX_COMPILATION_CACHE_DIR when set, else "
+                         "<repo>/.jax_cache) so cold calls hit disk instead "
+                         "of recompiling")
     ap.add_argument("--stress", action="store_true",
                     help="memory-bound chunked stress point only: stream "
                          ">= 20k events per replica through the chunked "

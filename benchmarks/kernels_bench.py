@@ -48,7 +48,6 @@ def bench_fused(rng, rows=None):
     from repro.core.policy import resolve
     from repro.sim import batched
 
-    interp = jax.default_backend() != "tpu"
     print("table,kernel,shape,us_fused_pallas,us_jnp")
     pid = 2
     for m in (1024, 4096):
@@ -58,7 +57,7 @@ def bench_fused(rng, rows=None):
         vg = tables.V[midx]
         base, free, f = _engine_state(spec, tables, rng)
         pspec = resolve("mfi", engine="batched")
-        select_fn = batched.make_select_fn(spec, pspec, interpret=interp)
+        select_fn = batched.make_select_fn(spec, pspec)
         fused = jax.jit(lambda b, fr, ff: select_fn(b, fr, ff, pid))
         ref = jax.jit(
             lambda b, fr, ff: batched._select(
@@ -84,7 +83,7 @@ def bench_fused(rng, rows=None):
     rp = jnp.asarray(rng.integers(0, mig.NUM_PROFILES, size=c), jnp.int32)
     kc = jnp.zeros((c,), jnp.int32)
     migrate_fn = batched.make_migrate_fn(
-        spec, resolve("mfi-defrag", engine="batched"), interpret=interp
+        spec, resolve("mfi-defrag", engine="batched")
     )
     mig_j = jax.jit(lambda *a: migrate_fn(*a))
     us_k = time_fn(

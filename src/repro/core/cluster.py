@@ -15,7 +15,7 @@ their oracle at cluster scale.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -184,15 +184,16 @@ def mfi_select(
     metric: str = "blocked",
     tables: DeviceTables = None,
     use_kernel: bool = False,
-    interpret: bool = None,
+    interpret: Optional[bool] = None,
 ) -> MFIDecision:
     """Algorithm 2's argmin over all feasible (GPU, anchor) dry-runs.
 
     The single entry point for both lowerings: the pure-jnp dense dry-run
     (default) and the fused Pallas ``mfi_delta`` kernel (``use_kernel=True``
-    — feasibility + ΔF in one launch; ``interpret`` defaults to interpret
-    mode off-TPU).  Both produce the identical decision: scores are
-    integer-valued, the argmin's first-occurrence tie-break is shared.
+    — feasibility + ΔF in one launch; ``interpret`` as in
+    :func:`repro.kernels.interpret_mode`).  Both produce the identical
+    decision: scores are integer-valued, the argmin's first-occurrence
+    tie-break is shared.
 
     Args:
       occ: (M, S) int32 occupancy of same-model GPUs (``tables`` selects the
@@ -204,7 +205,6 @@ def mfi_select(
     if use_kernel:
         from repro.kernels.fragscore import fragscore as _k
 
-        interp = jax.default_backend() != "tpu" if interpret is None else interpret
         big = jnp.float32(1e30)  # the kernel's own infeasibility sentinel
         scored = _k.mfi_delta(
             occ,
@@ -213,7 +213,7 @@ def mfi_select(
             t.profile_masks[profile_id],
             t.profile_valid[profile_id].astype(jnp.float32),
             metric=metric,
-            interpret=interp,
+            interpret=interpret,
         )
     else:
         feasible = placement_feasibility(occ, profile_id, tables)

@@ -18,11 +18,14 @@ uses a per-batch ``length`` operand.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 DEFAULT_BLK_S = 512
 
@@ -78,7 +81,7 @@ def decode_attention(
     *,
     scale: float | None = None,
     blk_s: int = DEFAULT_BLK_S,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, h, d = q.shape
     s, kheads = k.shape[1], k.shape[2]
@@ -112,6 +115,6 @@ def decode_attention(
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(length.astype(jnp.int32), qg, k, v)
     return out.reshape(b, h, d)

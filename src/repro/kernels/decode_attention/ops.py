@@ -9,10 +9,6 @@ from repro.kernels.decode_attention.decode_attention import decode_attention as 
 from repro.kernels.decode_attention.ref import decode_attention_ref
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def gqa_decode_attention(
     q: jax.Array,
     k: jax.Array,
@@ -32,4 +28,4 @@ def gqa_decode_attention(
         length = jnp.full((q.shape[0],), k.shape[1], jnp.int32)
     if not use_kernel:
         return decode_attention_ref(q, k, v, scale=scale, length=length)
-    return _kernel(q, k, v, length, scale=scale, blk_s=blk_s, interpret=_use_interpret())
+    return _kernel(q, k, v, length, scale=scale, blk_s=blk_s)

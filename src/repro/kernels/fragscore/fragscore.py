@@ -45,13 +45,30 @@ bit-for-bit.  See ``docs/KERNELS.md`` for the packing scheme.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NUM_SLICES = 8  # canonical A100-style geometry (kernels accept any S)
 BLK_M = 512  # GPUs per VMEM slab (512×8 f32 = 16 KiB)
+
+# Block-shape rule (Mosaic): the last two dims of every block are (8, 128)
+# multiples or the array's own.  Under ``vmap`` a replica dim is prepended
+# and squeezed, so a 1-D per-request operand such as ``(A,)`` becomes an
+# ``(R, A)`` array with a squeezed second-minor dim, which Mosaic refuses.
+# Per-request vectors therefore travel as ``(1, A)`` rows, per-request
+# scalars as ``(1, 1)`` SMEM blocks (:func:`_smem`), and per-tile
+# output rows as ``(1, W)`` trailing blocks of a ``(T, 1, W)`` array.
+
+
+def _smem(shape):
+    """A whole-array SMEM block for small per-call scalar tables."""
+    return pl.BlockSpec(shape, lambda *_: (0,) * len(shape), memory_space=pltpu.SMEM)
 
 
 def _score_block(occ, w, v, metric: str):
@@ -79,7 +96,7 @@ def fragscore(
     v: jax.Array,
     *,
     metric: str = "blocked",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """F(m) for every GPU.
 
@@ -88,7 +105,8 @@ def fragscore(
       w: (N, S) placement-window masks of the device model.
       v: (N,) memory-slice weights.
       metric: "blocked" | "partial".
-      interpret: run in interpret mode (CPU validation); False on real TPU.
+      interpret: see :func:`repro.kernels.interpret_mode` (default: Mosaic
+        on TPU, the interpreter elsewhere).
 
     Returns:
       (M,) float32.
@@ -107,7 +125,7 @@ def fragscore(
         ],
         out_specs=pl.BlockSpec((BLK_M, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, 1), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(occ_p.astype(jnp.float32), w.astype(jnp.float32), v.astype(jnp.float32))
     return out[:m, 0]
 
@@ -138,7 +156,7 @@ def mfi_delta(
     profile_valid: jax.Array,
     *,
     metric: str = "blocked",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused Algorithm-2 inner loop: ΔF over all (GPU, anchor) dry-runs.
 
@@ -169,7 +187,7 @@ def mfi_delta(
         ],
         out_specs=pl.BlockSpec((BLK_M, a), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, a), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(
         occ_p.astype(jnp.float32),
         w.astype(jnp.float32),
@@ -240,7 +258,7 @@ def _delta_from_base_kernel(
     """Fused ΔF dry-run table from the incremental window-count state."""
     out_ref[...] = _delta_block(
         base_ref[...], free_ref[...][:, 0], f_ref[...][:, 0], v_ref[...],
-        mw_ref[...], mp_ref[...], mem_ref[0], metric,
+        mw_ref[...], mp_ref[...], mem_ref[0, 0], metric,
     )
 
 
@@ -255,7 +273,7 @@ def delta_from_base(
     f_before: jax.Array,
     *,
     metric: str = "blocked",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """ΔF of every anchor dry-run of one request, from window counts.
 
@@ -274,7 +292,8 @@ def delta_from_base(
       mem: scalar — the request's slice demand on this model.
       f_before: (M,) float32 — current F(m) scores.
       metric: "blocked" | "partial".
-      interpret: run in interpret mode (CPU validation); False on real TPU.
+      interpret: see :func:`repro.kernels.interpret_mode` (default: Mosaic
+        on TPU, the interpreter elsewhere).
 
     Returns:
       (M, A) float32 ΔF table.
@@ -298,11 +317,11 @@ def delta_from_base(
             pl.BlockSpec((n,), lambda i: (0,)),
             pl.BlockSpec((a, n), lambda i: (0, 0)),
             pl.BlockSpec((a, n), lambda i: (0, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            _smem((1, 1)),
         ],
         out_specs=pl.BlockSpec((BLK_M, a), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, a), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(
         base_p,
         free_p,
@@ -310,8 +329,8 @@ def delta_from_base(
         v.astype(jnp.float32),
         mw.astype(jnp.float32),
         mp.astype(jnp.float32),
-        jnp.reshape(mem, (1,)).astype(jnp.float32),
-        )
+        jnp.reshape(mem, (1, 1)).astype(jnp.float32),
+    )
     return out[:m]
 
 
@@ -326,14 +345,21 @@ def delta_from_base(
 BIG = 1e9
 
 
-def _blk_rows(m: int) -> int:
-    """Adaptive row-tile: whole problem when it fits, BLK_M slabs beyond.
+#: victim rows per tile of the migrate search's pass 1.  Each victim row
+#: carries its own ``(A, N)`` anchor table, which pads to a whole (8, 128)
+#: tile in VMEM (4 KiB per row, double-buffered, plus ``(A, N)``-shaped
+#: temporaries), so pass 1 tiles narrower than the per-GPU slabs of pass 0.
+BLK_V = 128
+
+
+def _blk_rows(m: int, cap: int = BLK_M) -> int:
+    """Adaptive row-tile: whole problem when it fits, ``cap``-row slabs beyond.
 
     Fleets are usually far smaller than BLK_M; padding 16 rows to 512 would
     make every fused launch 32× wider than the work.  TPU f32 tiles are
     (8, 128), so round up to a multiple of 8.
     """
-    return min(BLK_M, -(-m // 8) * 8)
+    return min(cap, -(-m // 8) * 8)
 
 
 def _key_tile(base_key, sign, delta, free, mem, gid, anchors, shape):
@@ -430,14 +456,14 @@ def _select_from_base_kernel(
     f = f_ref[...][:, 0]
     gid = gidx_ref[...][:, 0]
     live = live_ref[...][:, 0] > 0
-    mem = mem_ref[0]
+    mem = mem_ref[0, 0]
     blk = base.shape[0]
-    a = valid_ref.shape[0]
+    a = valid_ref.shape[-1]
 
     # feasibility: the request's anchor windows hold zero occupied slices —
     # a one-hot gather ``base @ rowsel`` on the MXU (exact: single terms)
     overlap = jnp.dot(base, rowsel_ref[...], preferred_element_type=jnp.float32)
-    feas = (overlap == 0) & (valid_ref[...][None, :] > 0) & live[:, None]
+    feas = (overlap == 0) & (valid_ref[...] > 0) & live[:, None]
 
     delta = None
     if any(b == "frag-delta" for b, _ in keys):
@@ -485,7 +511,7 @@ def select_from_base(
     *,
     keys,
     metric: str = "blocked",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused select over one model group: per-tile winner rows.
 
@@ -507,6 +533,7 @@ def select_from_base(
       rowsel: (N, A) one-hot of ``profile_rows`` — feasibility gather.
       valid: (A,) anchor validity (1.0 real / 0.0 padded).
       anchors: (A,) anchor *values* (``profile_anchors``).
+      Both travel to the kernel as ``(1, A)`` rows.
       keys: static ``((base_key, sign), …)`` effective scoring keys.
 
     Returns:
@@ -524,7 +551,7 @@ def select_from_base(
     live_p = jnp.zeros((m_pad, 1), jnp.float32).at[:m, 0].set(1.0)
     l = len(keys)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_select_from_base_kernel, metric=metric, keys=keys),
         grid=(t,),
         in_specs=[
@@ -536,14 +563,14 @@ def select_from_base(
             pl.BlockSpec((n,), lambda i: (0,)),
             pl.BlockSpec((a, n), lambda i: (0, 0)),
             pl.BlockSpec((a, n), lambda i: (0, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            _smem((1, 1)),
             pl.BlockSpec((n, a), lambda i: (0, 0)),
-            pl.BlockSpec((a,), lambda i: (0,)),
-            pl.BlockSpec((a,), lambda i: (0,)),
+            pl.BlockSpec((1, a), lambda i: (0, 0)),
+            pl.BlockSpec((1, a), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, l + 3), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, l + 3), jnp.float32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((None, 1, l + 3), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((t, 1, l + 3), jnp.float32),
+        interpret=interpret_mode(interpret),
     )(
         base_p,
         col(free),
@@ -553,11 +580,12 @@ def select_from_base(
         v.astype(jnp.float32),
         mw.astype(jnp.float32),
         mp.astype(jnp.float32),
-        jnp.reshape(mem, (1,)).astype(jnp.float32),
+        jnp.reshape(mem, (1, 1)).astype(jnp.float32),
         rowsel.astype(jnp.float32),
-        valid.astype(jnp.float32),
-        anchors.astype(jnp.float32),
+        valid.astype(jnp.float32).reshape(1, a),
+        anchors.astype(jnp.float32).reshape(1, a),
     )
+    return out.reshape(t, l + 3)
 
 
 def _class_pass_impl(
@@ -583,7 +611,7 @@ def _class_pass_impl(
     need_delta = any(b == "frag-delta" for b, _ in keys)
     rows = []
     for p in range(p_):
-        mem = mem_all_ref[p]
+        mem = mem_all_ref[0, p]
         overlap = jnp.dot(
             base, rowsel_all_ref[p], preferred_element_type=jnp.float32
         )
@@ -603,8 +631,8 @@ def _class_pass_impl(
 
 
 def _victim_pass_impl(
-    base2_ref, free2_ref, f2_ref, vgid_ref, vv_ref, vmw_ref, vmp_ref,
-    vmem_ref, vrowsel_ref, vvalid_ref, vanchors_ref, out1_ref,
+    base2_ref, free2_ref, f2_ref, vgid_ref, vv_ref, vmw_ref,
+    vmem_ref, vrows_ref, vvalid_ref, vanchors_ref, out1_ref,
     *, metric: str, keys,
 ):
     """Pass 1: per-victim patched-row refinement.
@@ -613,21 +641,29 @@ def _victim_pass_impl(
     fleets gather per victim) — the row-wise ΔF form.  Emits
     ``[keys…, col, ok]`` per victim; column 0 (unmasked values) when no
     anchor survives, matching the jnp path's argmax-of-mask semantics.
+    The anchor windows' occupied counts are gathered in-kernel from the
+    ``(blk, A)`` placement-row ids (one select per window), and the
+    ``mw > 0`` indicator is derived from ``mw``: per-victim ``(N, A)``
+    one-hots and indicator tables would pad to whole VMEM tiles per row.
     """
     base2 = base2_ref[...]                    # (blk, N)
     free2 = free2_ref[...][:, 0]
     f2 = f2_ref[...][:, 0]
     vgid = vgid_ref[...][:, 0]
     vmem = vmem_ref[...][:, 0]
-    blk = base2.shape[0]
+    rows = vrows_ref[...]                     # (blk, A) float row ids
+    blk, n = base2.shape
     a = vvalid_ref.shape[-1]
-    overlap = jnp.sum(base2[:, :, None] * vrowsel_ref[...], axis=1)  # (blk, A)
+    overlap = jnp.zeros((blk, a), jnp.float32)
+    for j in range(n):  # exactly one window per anchor: exact sum
+        overlap = overlap + jnp.where(rows == j, base2[:, j:j + 1], 0.0)
     feas = (overlap == 0) & (vvalid_ref[...] > 0)
     delta = None
     if any(b == "frag-delta" for b, _ in keys):
+        vmw = vmw_ref[...]
         delta = _delta_rows(
-            base2, free2, f2, vv_ref[...], vmw_ref[...], vmp_ref[...],
-            vmem, metric,
+            base2, free2, f2, vv_ref[...], vmw,
+            (vmw > 0).astype(jnp.float32), vmem, metric,
         )
     vals = [
         _key_tile(b, s, delta, free2, vmem, vgid, vanchors_ref[...], (blk, a))
@@ -643,20 +679,19 @@ def _migrate_class_kernel(*refs, metric: str, keys):
     _class_pass_impl(*refs, metric=metric, keys=keys)
 
 
-def _migrate_refine_kernel(passid_ref, *refs, metric: str, keys):
+def _migrate_refine_kernel(*refs, metric: str, keys):
     """Both migrate refinements in one launch; the second grid dimension
-    selects the pass.  The pass id arrives as a (1, 1) operand indexed by
-    the grid (never ``pl.program_id`` — vmap over replicas prepends a batch
-    grid dimension and would shift the axis numbering)."""
-    pid = passid_ref[0, 0]
-    class_in, victim_in = refs[:12], refs[12:23]
-    out0_ref, out1_ref = refs[23], refs[24]
+    selects the pass.  ``pl.program_id`` counts the kernel's own grid axes
+    only, so the replica axis ``vmap`` prepends does not shift it."""
+    pid = pl.program_id(1)
+    class_in, victim_in = refs[:12], refs[12:22]
+    out0_ref, out1_ref = refs[22], refs[23]
 
-    @pl.when(pid == 0.0)
+    @pl.when(pid == 0)
     def _():
         _class_pass_impl(*class_in, out0_ref, metric=metric, keys=keys)
 
-    @pl.when(pid == 1.0)
+    @pl.when(pid == 1)
     def _():
         _victim_pass_impl(*victim_in, out1_ref, metric=metric, keys=keys)
 
@@ -683,7 +718,7 @@ def migrate_refine(
     *,
     keys,
     metric: str = "blocked",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Fused migrate-search refinements over one model group.
 
@@ -704,8 +739,9 @@ def migrate_refine(
       mw_all/mp_all: (P, A, N) per-class anchor tables; mem_all: (P,).
       rowsel_all: (P, N, A) one-hot feasibility gathers; valid_all /
         anchors_all: (P, A).
-      victims: optional tuple ``(base2, free2, f2, vgid, vv, vmw, vmp,
-        vmem, vrowsel, vvalid, vanchors)`` of per-victim (C, …) tables.
+      victims: optional tuple ``(base2, free2, f2, vgid, vv, vmw, vmem,
+        vrows, vvalid, vanchors)`` of per-victim (C, …) tables; ``vrows``
+        (C, A) holds each anchor's placement-row id (``profile_rows``).
       keys: static ``((base_key, sign), …)`` effective scoring keys.
 
     Returns:
@@ -731,7 +767,7 @@ def migrate_refine(
         v.astype(jnp.float32),
         mw_all.astype(jnp.float32),
         mp_all.astype(jnp.float32),
-        mem_all.astype(jnp.float32),
+        mem_all.astype(jnp.float32).reshape(1, p_),
         rowsel_all.astype(jnp.float32),
         valid_all.astype(jnp.float32),
         anchors_all.astype(jnp.float32),
@@ -747,7 +783,7 @@ def migrate_refine(
             pl.BlockSpec((n,), lambda i: (0,)),
             pl.BlockSpec((p_, a, n), lambda i: (0, 0, 0)),
             pl.BlockSpec((p_, a, n), lambda i: (0, 0, 0)),
-            pl.BlockSpec((p_,), lambda i: (0,)),
+            _smem((1, p_)),
             pl.BlockSpec((p_, n, a), lambda i: (0, 0, 0)),
             pl.BlockSpec((p_, a), lambda i: (0, 0)),
             pl.BlockSpec((p_, a), lambda i: (0, 0)),
@@ -758,14 +794,13 @@ def migrate_refine(
             in_specs=class_specs,
             out_specs=pl.BlockSpec((1, p_, w0), lambda i: (i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((t0, p_, w0), jnp.float32),
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(*class_ops)
         return out0, None
 
-    (base2, free2, f2, vgid, vv, vmw, vmp, vmem, vrowsel, vvalid,
-     vanchors) = victims
+    (base2, free2, f2, vgid, vv, vmw, vmem, vrows, vvalid, vanchors) = victims
     c = base2.shape[0]
-    blk1 = _blk_rows(c)
+    blk1 = _blk_rows(c, BLK_V)
     c_pad = -(-c // blk1) * blk1
     t1 = c_pad // blk1
     t = max(t0, t1)
@@ -778,9 +813,8 @@ def migrate_refine(
         colv(vgid),
         _pad_rows(vv, c, c_pad),
         _pad_rows(vmw, c, c_pad),
-        _pad_rows(vmp, c, c_pad),
         colv(vmem),
-        _pad_rows(vrowsel, c, c_pad),
+        _pad_rows(vrows, c, c_pad),
         _pad_rows(vvalid, c, c_pad),  # zero-padded validity masks pad victims
         _pad_rows(vanchors, c, c_pad),
     )
@@ -788,7 +822,6 @@ def migrate_refine(
     i0 = lambda i, j: (jnp.minimum(i, t0 - 1), 0)  # noqa: E731
     i1 = lambda i, j: (jnp.minimum(i, t1 - 1), 0)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, 1), lambda i, j: (j, 0)),  # pass id
         # -- pass 0 operands (clamped to the class tiles) -------------------
         pl.BlockSpec((blk0, n), i0),
         pl.BlockSpec((blk0, 1), i0),
@@ -798,7 +831,7 @@ def migrate_refine(
         pl.BlockSpec((n,), lambda i, j: (0,)),
         pl.BlockSpec((p_, a, n), lambda i, j: (0, 0, 0)),
         pl.BlockSpec((p_, a, n), lambda i, j: (0, 0, 0)),
-        pl.BlockSpec((p_,), lambda i, j: (0,)),
+        _smem((1, p_)),
         pl.BlockSpec((p_, n, a), lambda i, j: (0, 0, 0)),
         pl.BlockSpec((p_, a), lambda i, j: (0, 0)),
         pl.BlockSpec((p_, a), lambda i, j: (0, 0)),
@@ -809,13 +842,11 @@ def migrate_refine(
         pl.BlockSpec((blk1, 1), i1),
         pl.BlockSpec((blk1, n), i1),
         pl.BlockSpec((blk1, a, n), lambda i, j: (jnp.minimum(i, t1 - 1), 0, 0)),
-        pl.BlockSpec((blk1, a, n), lambda i, j: (jnp.minimum(i, t1 - 1), 0, 0)),
         pl.BlockSpec((blk1, 1), i1),
-        pl.BlockSpec((blk1, n, a), lambda i, j: (jnp.minimum(i, t1 - 1), 0, 0)),
+        pl.BlockSpec((blk1, a), i1),
         pl.BlockSpec((blk1, a), i1),
         pl.BlockSpec((blk1, a), i1),
     ]
-    passid = jnp.arange(2, dtype=jnp.float32).reshape(2, 1)
     out0, out1 = pl.pallas_call(
         functools.partial(_migrate_refine_kernel, metric=metric, keys=keys),
         grid=(t, 2),
@@ -828,6 +859,6 @@ def migrate_refine(
             jax.ShapeDtypeStruct((t0, p_, w0), jnp.float32),
             jax.ShapeDtypeStruct((c_pad, l + 2), jnp.float32),
         ],
-        interpret=interpret,
-    )(passid, *class_ops, *victim_ops)
+        interpret=interpret_mode(interpret),
+    )(*class_ops, *victim_ops)
     return out0, out1[:c]

@@ -18,15 +18,9 @@ _W = np.asarray(mig.PLACEMENT_MASKS, dtype=np.float32)
 _V = np.asarray(mig.PLACEMENT_MEM, dtype=np.float32)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def fragmentation_scores(occ: jax.Array, metric: str = "blocked") -> jax.Array:
     """Kernel-backed F(m) over the cluster: (M, 8) -> (M,) float32."""
-    return _k.fragscore(
-        occ, jnp.asarray(_W), jnp.asarray(_V), metric=metric, interpret=_use_interpret()
-    )
+    return _k.fragscore(occ, jnp.asarray(_W), jnp.asarray(_V), metric=metric)
 
 
 def mfi_delta_f(occ: jax.Array, profile_id, metric: str = "blocked") -> jax.Array:
@@ -40,7 +34,6 @@ def mfi_delta_f(occ: jax.Array, profile_id, metric: str = "blocked") -> jax.Arra
         masks,
         valid,
         metric=metric,
-        interpret=_use_interpret(),
     )
 
 
@@ -72,7 +65,6 @@ def delta_from_base_f(
         jnp.asarray(mig.PROFILE_MEM)[profile_id],
         f_before,
         metric=metric,
-        interpret=_use_interpret(),
     )
 
 

@@ -99,11 +99,12 @@ Migrate stage (batched ``mfi-defrag``)
 
 Replica sharding
     The replica axis is embarrassingly parallel: :func:`run_batched`
-    splits it across all visible devices via ``jax.sharding``
-    (``NamedSharding`` over a 1-D ``replicas`` mesh) whenever more than
-    one device is available and ``runs`` divides evenly — results are
-    bitwise identical to the single-device run (no cross-replica
-    arithmetic happens on device).  Single-device setups are unchanged.
+    splits it across all visible devices (``NamedSharding`` over a 1-D
+    ``replicas`` mesh, the scan mapped over it with ``shard_map`` since
+    XLA cannot partition a Mosaic kernel) whenever more than one device
+    is available and ``runs`` divides evenly — results are bitwise
+    identical to the single-device run (no cross-replica arithmetic
+    happens on device).  Single-device setups are unchanged.
 
 Chunked streaming driver (``chunk_size``)
     By default the whole ``(E_max, R)`` event stream ships to device and
@@ -438,14 +439,13 @@ def make_frag_fn(
     interpret: Optional[bool] = None,
 ):
     """(N, S) occupancy -> (N,) F scores; Pallas kernel when ``use_kernel``
-    (``interpret`` defaults to interpret mode off-TPU)."""
+    (``interpret`` as in :func:`repro.kernels.interpret_mode`)."""
     if use_kernel:
         from repro.kernels.fragscore import fragscore as _k
 
         w = jnp.asarray(model.placement_masks, dtype=jnp.float32)
         v = jnp.asarray(model.placement_mem, dtype=jnp.float32)
-        interp = (jax.default_backend() != "tpu") if interpret is None else interpret
-        return lambda occ: _k.fragscore(occ, w, v, metric=metric, interpret=interp)
+        return lambda occ: _k.fragscore(occ, w, v, metric=metric, interpret=interpret)
     tables = jcluster.tables_for(model)
     return functools.partial(jcluster.frag_scores, metric=metric, tables=tables)
 
@@ -466,15 +466,14 @@ def make_delta_fn(
     masked-refinement select consumes.  This is how ``use_kernel`` works on
     *mixed* fleets — the occupancy-based ``fragscore`` kernel still
     requires a homogeneous spec (it bakes in one table), but the ΔF path
-    only needs per-group window counts.  ``interpret`` defaults to
-    interpret mode off-TPU (CPU validation).
+    only needs per-group window counts.  ``interpret`` as in
+    :func:`repro.kernels.interpret_mode`.
     """
     from repro.kernels.fragscore import fragscore as _k
 
     tables = spec_tables(spec)
     groups = spec.model_groups()  # static (model, numpy GPU-id array) pairs
     a = int(tables.profile_rows.shape[-1])
-    interp = (jax.default_backend() != "tpu") if interpret is None else interpret
 
     def delta_fn(base, free, f, pid):
         out = jnp.zeros((base.shape[0], a), jnp.float32)
@@ -489,7 +488,7 @@ def make_delta_fn(
                 tables.profile_mem[k, pid],
                 f[ridx],
                 metric=metric,
-                interpret=interp,
+                interpret=interpret,
             )
             out = out.at[ridx].set(d)
         return out
@@ -557,7 +556,6 @@ def make_select_fn(
     groups = spec.model_groups()
     keys = _effective_keys(pspec)
     l = len(keys)
-    interp = (jax.default_backend() != "tpu") if interpret is None else interpret
     arange_n = jnp.arange(int(tables.V.shape[-1]), dtype=jnp.int32)
 
     def select_fn(base, free, f, pid):
@@ -580,7 +578,7 @@ def make_select_fn(
                     tables.profile_anchors[k, pid],
                     keys=keys,
                     metric=metric,
-                    interpret=interp,
+                    interpret=interpret,
                 )
             )
         return _lex_pick_rows(jnp.concatenate(cand, axis=0), l)
@@ -645,7 +643,6 @@ def make_migrate_fn(
     keys = _effective_keys(pspec)
     l = len(keys)
     p_ = int(tables.profile_rows.shape[1])
-    interp = (jax.default_backend() != "tpu") if interpret is None else interpret
     arange_n = jnp.arange(int(tables.V.shape[-1]), dtype=jnp.int32)
     # (K, P, N, A) one-hot feasibility gathers — static per spec
     rowsel_all = (
@@ -653,14 +650,10 @@ def make_migrate_fn(
     ).astype(jnp.float32)
 
     def migrate_fn(base, free, f, base2, free2, f2, rg, rp, kc):
-        vrowsel = (
-            tables.profile_rows[kc, rp][:, None, :] == arange_n[None, :, None]
-        ).astype(jnp.float32)                  # (C, N, A)
         victims = (
             base2, free2, f2, rg.astype(jnp.float32),
-            tables.V[kc],
-            tables.maskwin[kc, rp], tables.maskpos[kc, rp],
-            tables.profile_mem[kc, rp], vrowsel,
+            tables.V[kc], tables.maskwin[kc, rp], tables.profile_mem[kc, rp],
+            tables.profile_rows[kc, rp].astype(jnp.float32),
             tables.profile_valid[kc, rp], tables.profile_anchors[kc, rp],
         )
         cands, out1 = [], None
@@ -681,7 +674,7 @@ def make_migrate_fn(
                 victims if k == 0 else None,
                 keys=keys,
                 metric=metric,
-                interpret=interp,
+                interpret=interpret,
             )
             if o1 is not None:
                 out1 = o1
@@ -2226,11 +2219,32 @@ def _scan_xs(events: EventStream, proto: Protocol):
     return xs
 
 
+#: the mesh axis the replica dimension is split over (:func:`_replica_sharding`)
+REPLICAS = "replicas"
+
+
+def _per_device(scan, mesh, in_specs):
+    """``scan`` mapped over the replica mesh, each device on its own slice.
+
+    XLA cannot partition a Mosaic kernel, so a replica-sharded scan is
+    mapped over the mesh explicitly.  Replicas never interact on device,
+    so D devices scanning R/D replicas each are bit-identical to one
+    device scanning all R.  ``scan`` returns ``(carry, trace)``: the
+    carry's replica axis leads, the trace's follows the event axis.
+    """
+    p = jax.sharding.PartitionSpec
+    return jax.shard_map(
+        scan, mesh=mesh, in_specs=in_specs,
+        out_specs=(p(REPLICAS), p(None, REPLICAS)), check_vma=False,
+    )
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
         "policy", "metric", "num_gpus", "ring_rows", "ring_cols",
         "use_kernel", "kernel_spec", "protocol", "wait_slots", "wait_patience",
+        "mesh",
     ),
 )
 def _simulate(
@@ -2248,18 +2262,30 @@ def _simulate(
     wait_patience: int = 0,
     midx: Optional[jax.Array] = None,
     tables: Optional[SpecTables] = None,
+    mesh: Optional[jax.sharding.Mesh] = None,
 ) -> Tuple[ReplicaState, EventTrace]:
-    runs = events.pid.shape[1]
-    core, tables, midx = _build_core(
-        policy=policy, metric=metric, num_gpus=num_gpus,
-        use_kernel=use_kernel, kernel_spec=kernel_spec, protocol=protocol,
-        wait_slots=wait_slots, wait_patience=wait_patience,
-        midx=midx, tables=tables,
-    )
-    step = jax.vmap(core.step, in_axes=(0, 0))
-    init = _broadcast_init(core, runs, ring_rows, ring_cols, wait_slots)
-    return jax.lax.scan(
-        lambda st, x: step(st, x), init, _scan_xs(events, core.protocol)
+    """The event scan over all replicas; ``mesh`` (the replica mesh of
+    :func:`_replica_sharding`) runs it per device (:func:`_per_device`)."""
+
+    def scan(events, midx, tables):
+        core, tables, midx = _build_core(
+            policy=policy, metric=metric, num_gpus=num_gpus,
+            use_kernel=use_kernel, kernel_spec=kernel_spec, protocol=protocol,
+            wait_slots=wait_slots, wait_patience=wait_patience,
+            midx=midx, tables=tables,
+        )
+        step = jax.vmap(core.step, in_axes=(0, 0))
+        runs = events.pid.shape[1]
+        init = _broadcast_init(core, runs, ring_rows, ring_cols, wait_slots)
+        return jax.lax.scan(
+            lambda st, x: step(st, x), init, _scan_xs(events, core.protocol)
+        )
+
+    if mesh is None:
+        return scan(events, midx, tables)
+    p = jax.sharding.PartitionSpec
+    return _per_device(scan, mesh, (p(None, REPLICAS), p(), p()))(
+        events, midx, tables
     )
 
 
@@ -2519,9 +2545,11 @@ def _replica_sharding(runs: int, shard: Optional[bool] = None):
                 f"runs={runs} does not divide across {len(devices)} devices"
             )
         return None
-    mesh = jax.make_mesh((len(devices),), ("replicas",))
+    mesh = jax.make_mesh(
+        (len(devices),), (REPLICAS,), axis_types=(jax.sharding.AxisType.Auto,)
+    )
     return jax.sharding.NamedSharding(
-        mesh, jax.sharding.PartitionSpec(None, "replicas")
+        mesh, jax.sharding.PartitionSpec(None, REPLICAS)
     )
 
 
@@ -2530,8 +2558,8 @@ def shard_events(events, runs: int, shard: Optional[bool] = None):
 
     Replicas are embarrassingly parallel (no cross-replica arithmetic on
     device), so placing the ``(E_max, R)`` inputs on a 1-D ``replicas``
-    mesh lets XLA partition the whole scan — bitwise-identical results,
-    R/D replicas of work per device.  ``shard=None`` (auto) shards when
+    mesh and scanning with that mesh (``_simulate(..., mesh=...)``) gives
+    bitwise-identical results with R/D replicas of work per device.  ``shard=None`` (auto) shards when
     more than one device is visible and ``runs`` divides evenly; ``True``
     requires it (raises otherwise); ``False`` disables.
 
@@ -2624,7 +2652,7 @@ def _init_carry_jit(
     donate_argnums=(0,),
     static_argnames=(
         "policy", "metric", "num_gpus", "use_kernel", "kernel_spec",
-        "protocol", "wait_slots", "wait_patience",
+        "protocol", "wait_slots", "wait_patience", "mesh",
     ),
 )
 def _scan_chunk(
@@ -2641,24 +2669,35 @@ def _scan_chunk(
     wait_patience: int = 0,
     midx: Optional[jax.Array] = None,
     tables: Optional[SpecTables] = None,
+    mesh: Optional[jax.sharding.Mesh] = None,
 ) -> Tuple[ReplicaState, EventTrace]:
     """Scan one event chunk from an explicit carry (the chunked step).
 
     Identical scan body to :func:`_simulate` (same :func:`_build_core`
-    path, same vmapped :meth:`EngineCore.step`), with the carry passed in
-    instead of built internally and its input buffers **donated** — XLA
-    writes the updated carry back into the chunk's input storage, so the
-    resident state footprint stays one carry regardless of chunk count.
+    path, same vmapped :meth:`EngineCore.step`, same ``mesh`` handling),
+    with the carry passed in instead of built internally and its input
+    buffers **donated** — XLA writes the updated carry back into the
+    chunk's input storage, so the resident state footprint stays one carry
+    regardless of chunk count.
     """
-    core, _, _ = _build_core(
-        policy=policy, metric=metric, num_gpus=num_gpus,
-        use_kernel=use_kernel, kernel_spec=kernel_spec, protocol=protocol,
-        wait_slots=wait_slots, wait_patience=wait_patience,
-        midx=midx, tables=tables,
-    )
-    step = jax.vmap(core.step, in_axes=(0, 0))
-    return jax.lax.scan(
-        lambda st, x: step(st, x), state, _scan_xs(events, core.protocol)
+
+    def scan(state, events, midx, tables):
+        core, _, _ = _build_core(
+            policy=policy, metric=metric, num_gpus=num_gpus,
+            use_kernel=use_kernel, kernel_spec=kernel_spec, protocol=protocol,
+            wait_slots=wait_slots, wait_patience=wait_patience,
+            midx=midx, tables=tables,
+        )
+        step = jax.vmap(core.step, in_axes=(0, 0))
+        return jax.lax.scan(
+            lambda st, x: step(st, x), state, _scan_xs(events, core.protocol)
+        )
+
+    if mesh is None:
+        return scan(state, events, midx, tables)
+    p = jax.sharding.PartitionSpec
+    return _per_device(scan, mesh, (p(REPLICAS), p(None, REPLICAS), p(), p()))(
+        state, events, midx, tables
     )
 
 
@@ -2798,7 +2837,8 @@ def simulate_chunked(
     buf, dt, nb = put(bounds[0], bounds[1])  # prefetch chunk 0 (not overlapped)
     h2d_s += dt
     h2d_bytes += nb
-    state, tr = _scan_chunk(state, buf, **statics)  # async dispatch
+    mesh = None if sharding is None else sharding.mesh
+    state, tr = _scan_chunk(state, buf, mesh=mesh, **statics)  # async dispatch
     traces = []
     for k in range(n_chunks):
         # chunk k's scan is already in flight; ``state`` is its output carry
@@ -2814,7 +2854,7 @@ def simulate_chunked(
             h2d_bytes += nb
             h2d_overlap_s += dt
             h2d_overlap_bytes += nb
-            state, tr_next = _scan_chunk(state, buf, **statics)
+            state, tr_next = _scan_chunk(state, buf, mesh=mesh, **statics)
         if stream:
             t0 = time.perf_counter()
             traces.append(jax.device_get(tr))  # joins chunk k's compute
@@ -2839,6 +2879,95 @@ def simulate_chunked(
         )
     concat = np.concatenate if stream else jnp.concatenate
     return state, _concat_traces(traces, concat)
+
+
+class BatchedProgram(NamedTuple):
+    """One validated batched run: its presampled stream and the static
+    configuration shared by :func:`_simulate` and :func:`simulate_chunked`.
+
+    ``kwargs`` are exactly what :func:`run_batched` passes to the scan, so
+    a caller that needs the per-event trace (parity checks, compile-only
+    inspection) runs the very program the entry point runs, and reduces
+    it with :meth:`aggregate` as the entry point does.
+    """
+
+    events: EventStream
+    meta: EventMeta
+    kwargs: dict
+    protocol: Protocol
+    spec: mig.ClusterSpec
+    cfg: SimConfig
+
+    def aggregate(self, trace: EventTrace) -> Dict[str, float]:
+        """The protocol's ``run_many``-keyed aggregates of a host trace."""
+        events, spec, runs = self.events, self.spec, self.events.pid.shape[1]
+        if self.protocol.name == "cumulative":
+            return _aggregate_cumulative(events, trace, spec, runs, self.cfg)
+        if self.protocol.faulted:
+            return _aggregate_faulted(events, trace, spec, runs)
+        if self.protocol.queued:
+            return _aggregate_queued(events, trace, spec, runs)
+        return aggregate(events, trace, spec, runs)
+
+
+def batched_program(
+    policy: PolicyLike,
+    cfg: SimConfig,
+    runs: int,
+    use_kernel: bool | None = None,
+) -> BatchedProgram:
+    """Validate ``policy`` × ``cfg`` and presample ``runs`` replicas.
+
+    ``use_kernel=None`` picks the Pallas lowering on TPU for every spec
+    that allows it (``PolicySpec.kernel_lowering``); see :func:`run_batched`.
+    """
+    policy = resolve(policy, engine="batched")
+    proto = resolve_protocol(cfg.protocol)
+    spec = cfg.spec()
+    if use_kernel is None:
+        use_kernel = bool(
+            jax.default_backend() == "tpu" and policy.kernel_lowering
+        )
+    if use_kernel and not policy.kernel_lowering:
+        raise ValueError(
+            f"policy {policy.name!r} opts out of Pallas kernel lowering "
+            "(PolicySpec.kernel_lowering=False); run with use_kernel=False"
+        )
+    if proto.faulted:
+        if cfg.fault_model is None:
+            raise ValueError(
+                f"protocol {proto.name!r} needs SimConfig.fault_model "
+                "(a repro.core.mig.FaultModel describing MTBF/MTTR)"
+            )
+        # retry/backoff ride in the (static, hashable) protocol descriptor
+        proto = dataclasses.replace(
+            proto,
+            fault_retries=cfg.fault_model.max_retries,
+            fault_backoff=cfg.fault_model.backoff_base,
+        )
+
+    if proto.name == "cumulative":
+        events, meta, ring_rows, ring_cols = presample_cumulative(cfg, runs)
+    else:
+        events, meta, ring_rows, ring_cols = presample_arrivals(
+            cfg, runs, queued=proto.queued,
+            fault_model=cfg.fault_model if proto.faulted else None,
+        )
+    kwargs = dict(
+        policy=policy,
+        metric=cfg.metric,
+        num_gpus=cfg.num_gpus,
+        ring_rows=ring_rows,
+        ring_cols=ring_cols,
+        use_kernel=use_kernel,
+        kernel_spec=spec if use_kernel else None,
+        protocol=proto,
+        wait_slots=cfg.wait_capacity if proto.queued else 0,
+        wait_patience=cfg.wait_patience if proto.queued else 0,
+        midx=jnp.asarray(spec.model_index),
+        tables=spec_tables(spec),
+    )
+    return BatchedProgram(events, meta, kwargs, proto, spec, cfg)
 
 
 def run_batched(
@@ -2877,58 +3006,14 @@ def run_batched(
     transfer/overlap telemetry.  ``chunk_size=None`` (default) keeps
     today's single-chunk monolithic scan.
     """
-    policy = resolve(policy, engine="batched")
-    proto = resolve_protocol(cfg.protocol)
-    spec = cfg.spec()
-    if use_kernel is None:
-        use_kernel = bool(
-            jax.default_backend() == "tpu" and policy.kernel_lowering
-        )
-    if use_kernel and not policy.kernel_lowering:
-        raise ValueError(
-            f"policy {policy.name!r} opts out of Pallas kernel lowering "
-            "(PolicySpec.kernel_lowering=False); run with use_kernel=False"
-        )
     if chunk_size is not None and chunk_size <= 0:
         raise ValueError(f"chunk_size must be positive, got {chunk_size}")
     if chunk_size is None and (stream is not None or stats is not None):
         raise ValueError(
             "stream/stats are chunked-driver knobs; pass chunk_size as well"
         )
-    if proto.faulted:
-        if cfg.fault_model is None:
-            raise ValueError(
-                f"protocol {proto.name!r} needs SimConfig.fault_model "
-                "(a repro.core.mig.FaultModel describing MTBF/MTTR)"
-            )
-        # retry/backoff ride in the (static, hashable) protocol descriptor
-        proto = dataclasses.replace(
-            proto,
-            fault_retries=cfg.fault_model.max_retries,
-            fault_backoff=cfg.fault_model.backoff_base,
-        )
-
-    if proto.name == "cumulative":
-        events, _, ring_rows, ring_cols = presample_cumulative(cfg, runs)
-    else:
-        events, _, ring_rows, ring_cols = presample_arrivals(
-            cfg, runs, queued=proto.queued,
-            fault_model=cfg.fault_model if proto.faulted else None,
-        )
-    common = dict(
-        policy=policy,
-        metric=cfg.metric,
-        num_gpus=cfg.num_gpus,
-        ring_rows=ring_rows,
-        ring_cols=ring_cols,
-        use_kernel=use_kernel,
-        kernel_spec=spec if use_kernel else None,
-        protocol=proto,
-        wait_slots=cfg.wait_capacity if proto.queued else 0,
-        wait_patience=cfg.wait_patience if proto.queued else 0,
-        midx=jnp.asarray(spec.model_index),
-        tables=spec_tables(spec),
-    )
+    prog = batched_program(policy, cfg, runs, use_kernel)
+    events, common = prog.events, prog.kwargs
     if chunk_size is not None:
         _, trace = simulate_chunked(
             events,
@@ -2940,15 +3025,13 @@ def run_batched(
         )
         trace = jax.device_get(trace)  # no-op for already-streamed traces
     else:
+        sharding = _replica_sharding(runs, shard)
         events_dev = shard_events(jax.tree.map(jnp.asarray, events), runs, shard)
-        _, trace = jax.device_get(_simulate(events_dev, **common))
-    if proto.name == "cumulative":
-        return _aggregate_cumulative(events, trace, spec, runs, cfg)
-    if proto.faulted:
-        return _aggregate_faulted(events, trace, spec, runs)
-    if proto.queued:
-        return _aggregate_queued(events, trace, spec, runs)
-    return aggregate(events, trace, spec, runs)
+        _, trace = jax.device_get(_simulate(
+            events_dev, mesh=None if sharding is None else sharding.mesh,
+            **common,
+        ))
+    return prog.aggregate(trace)
 
 
 def aggregate(

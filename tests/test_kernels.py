@@ -491,3 +491,36 @@ class TestDecodeAttentionKernel:
         np.testing.assert_allclose(
             np.asarray(got)[0], np.asarray(v)[0, 0], atol=1e-6, rtol=1e-6
         )
+
+
+class TestInterpretMode:
+    """`repro.kernels.interpret_mode` is the one place a kernel's lowering
+    is chosen: Mosaic on a TPU backend, the interpreter elsewhere, and
+    never the interpreter on a TPU."""
+
+    @pytest.mark.parametrize(
+        "backend,requested,expected",
+        [
+            ("cpu", None, True),
+            ("cpu", False, False),
+            ("cpu", True, True),
+            ("tpu", None, False),
+            ("tpu", False, False),
+        ],
+    )
+    def test_choice(self, monkeypatch, backend, requested, expected):
+        import jax
+
+        from repro.kernels import interpret_mode
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert interpret_mode(requested) is expected
+
+    def test_interpreter_refused_on_tpu(self, monkeypatch):
+        import jax
+
+        from repro.kernels import interpret_mode
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="hide the device"):
+            interpret_mode(True)
