@@ -1,0 +1,18 @@
+"""Device milliseconds per call of the select path's Pallas kernels: the
+fused ``select_from_base`` and the score-increment table
+``delta_from_base`` (the faulted path's select), matched by name; the
+largest over the devices.  Nothing when no such kernel ran."""
+
+from perfbench.lib import trace as tracelib
+
+PATTERN = r"select_from_base|delta_from_base"
+
+
+def read(ctx):
+    tr = ctx.trace
+    if tr is None or not tr.devices or not tr.calls():
+        return None
+    lo, hi = tr.window()
+    times = [tracelib.op_time(ops, PATTERN, lo, hi) for ops in tr.devices.values()]
+    times = [t for t in times if t is not None]
+    return max(times) * 1e-6 / len(tr.calls()) if times else None
