@@ -34,13 +34,6 @@ throughput must stay within 10% — both gated by ``--baseline`` — and the
 recorded ``h2d_overlap_frac`` tracks how much of the host→device event
 feed overlapped chunk compute.
 
-``--profile`` adds a per-stage wall-time breakdown of the ``EngineCore``
-pipeline (select / migrate / commit / expire, µs per event across the
-replica batch) for a defrag and a non-defrag spec, plus the queued
-protocol's ``wait`` / ``park`` stages (``mfi@steady-queued``), emitted
-under ``stage_profile`` in the JSON payload — the view that shows *where*
-an engine configuration spends its scan step.
-
 ``--baseline PATH`` diffs the run against a committed reference artifact
 (``benchmarks/BENCH_baseline.json``): the headline ``speedup_warm`` (the
 batched-vs-python ratio, machine-normalized) must not regress by more than
@@ -227,7 +220,6 @@ def bench_chunked(cfg: SimConfig, runs: int, chunk_size: int | None = None):
     e_max = events.pid.shape[0]
     if chunk_size is None:
         chunk_size = max(1, e_max // 2 + 1)
-    stats: dict = {}
     run_batched("mfi", cfg, runs=runs, chunk_size=chunk_size)  # compile + warm
     run_batched("mfi", cfg, runs=runs)
     dt_chunked = dt_mono = float("inf")
@@ -236,18 +228,29 @@ def bench_chunked(cfg: SimConfig, runs: int, chunk_size: int | None = None):
         run_batched("mfi", cfg, runs=runs)
         dt_mono = min(dt_mono, time.perf_counter() - t0)
         t0 = time.perf_counter()
-        r = run_batched(
-            "mfi", cfg, runs=runs, chunk_size=chunk_size, stats=stats
-        )
+        r = run_batched("mfi", cfg, runs=runs, chunk_size=chunk_size)
         dt_chunked = min(dt_chunked, time.perf_counter() - t0)
     return {
         "warm_rps": runs / dt_chunked,
         "monolithic_warm_rps": runs / dt_mono,
         "acceptance_rate": float(r["acceptance_rate"]),
         "chunk_size": chunk_size,
-        "chunks": stats["chunks"],
-        "events": stats["events"],
-        "h2d_overlap_frac": stats["h2d_overlap_frac"],
+        "events": e_max,
+        **_chunked_record(),
+    }
+
+
+def _chunked_record():
+    """The last chunked call's chunk count and ``h2d_overlap_frac``: the
+    share of its host→device bytes staged while a chunk's scan was in
+    flight, from the call's :mod:`repro.obs` counters."""
+    from repro import obs
+
+    call = obs.calls()[-1]
+    c = call.counters
+    return {
+        "chunks": len(call.find("dispatch")),
+        "h2d_overlap_frac": c.get("h2d_overlapped_bytes", 0) / c["h2d_bytes"],
     }
 
 
@@ -283,10 +286,10 @@ def bench_stress(num_gpus: int = 16, load: float = 0.85, runs: int = 2,
                 int(cfg.measure_horizons * grow * 1.05) + 1,
             ),
         )
-    stats: dict = {}
     t0 = time.perf_counter()
-    r = run_batched("mfi", cfg, runs=runs, chunk_size=chunk_size, stats=stats)
+    r = run_batched("mfi", cfg, runs=runs, chunk_size=chunk_size)
     dt = time.perf_counter() - t0
+    record = _chunked_record()
     chunk_frac = chunk_size / e_max
     return {
         "events": e_max,
@@ -294,160 +297,13 @@ def bench_stress(num_gpus: int = 16, load: float = 0.85, runs: int = 2,
         "num_gpus": num_gpus,
         "measure_horizons": cfg.measure_horizons,
         "chunk_size": chunk_size,
-        "chunks": stats["chunks"],
+        "chunks": record["chunks"],
         "device_feed_fraction": chunk_frac,  # staged chunk vs full tensor
         "cold_rps": runs / dt,
         "acceptance_rate": float(r["acceptance_rate"]),
-        "h2d_overlap_frac": stats["h2d_overlap_frac"],
+        "h2d_overlap_frac": record["h2d_overlap_frac"],
         "completed": True,
     }
-
-
-def profile_stages(cfg: SimConfig, runs: int, policies=("mfi", "mfi-defrag")):
-    """Per-stage warm wall-time of the ``EngineCore`` pipeline.
-
-    Builds each policy's staged core, drives one full warm run to obtain a
-    *representative* replica state (steady state at the configured load),
-    then times every stage as its own jitted + vmapped program: µs per
-    event across the whole replica batch — exactly the work one scan step
-    does per stage.  The defrag spec's ``migrate`` row is the one the
-    factored search optimizes; non-defrag specs have no migrate stage.
-
-    The select and migrate stages are attributed per lowering:
-    ``select_jnp_us`` / ``migrate_jnp_us`` time the pure-jnp masked
-    refinement, ``select_kernel_us`` / ``migrate_kernel_us`` the fused
-    Pallas kernels (in-kernel lexicographic argmin; interpret mode when
-    the benchmark runs on CPU) on the *same* representative state — the
-    side-by-side view of what the fusion buys per event.
-
-    The queued protocol's extra stages are attributed too: an
-    ``mfi@steady-queued`` entry times ``wait`` (wait-ring prune +
-    head-of-line admission attempt) and ``park`` (rejected-arrival
-    insert) against a representative above-saturation queued state.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.policy import resolve
-    from repro.sim import batched
-
-    spec = cfg.spec()
-    tables = batched.spec_tables(spec)
-    midx = jnp.asarray(spec.model_index)
-    vg = tables.V[midx]
-    events, _, ring_rows, ring_cols = batched.presample_arrivals(cfg, runs)
-    dev = jax.tree.map(jnp.asarray, events)
-
-    def timeit(fn, *args, iters=20):
-        jax.block_until_ready(fn(*args))  # compile + warm
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / iters * 1e6  # µs / event batch
-
-    out = {}
-    for policy in policies:
-        pspec = resolve(policy, engine="batched")
-        core = batched.EngineCore(
-            spec=pspec,
-            protocol=batched.resolve_protocol("steady"),
-            metric=cfg.metric,
-            tables=tables,
-            midx=midx,
-            vg=vg,
-        )
-        state, _ = batched._simulate(
-            dev, policy=policy, metric=cfg.metric, num_gpus=cfg.num_gpus,
-            ring_rows=ring_rows, ring_cols=ring_cols, use_kernel=False,
-            midx=midx, tables=tables,
-        )  # final (R,)-vmapped state: steady-state occupancy at this load
-        pid = jnp.full((runs,), 2, jnp.int32)
-        valid = jnp.ones((runs,), bool)
-        zeros = jnp.zeros((runs,), jnp.int32)
-        new_slot = jnp.ones((runs,), bool)
-
-        expire = jax.jit(jax.vmap(core._stage_expire))
-        select = jax.jit(jax.vmap(core._stage_select))
-        stages = {
-            "expire_us": timeit(expire, state, zeros, new_slot),
-            "select_jnp_us": timeit(select, state, pid, valid),
-        }
-        core_k = None
-        if pspec.fused_argmin:  # fused Pallas lowering on the same state
-            core_k = batched._build_core(
-                policy=policy, metric=cfg.metric, num_gpus=cfg.num_gpus,
-                use_kernel=True, kernel_spec=spec, midx=midx, tables=tables,
-            )[0]
-            select_k = jax.jit(jax.vmap(core_k._stage_select))
-            stages["select_kernel_us"] = timeit(select_k, state, pid, valid)
-        gpu, aidx, ok = select(state, pid, valid)
-        mig_res = None
-        if pspec.defrag:
-            migrate = jax.jit(jax.vmap(core._stage_migrate))
-            stages["migrate_jnp_us"] = timeit(
-                migrate, state, pid, valid, gpu, aidx, ok
-            )
-            if core_k is not None:
-                migrate_k = jax.jit(jax.vmap(core_k._stage_migrate))
-                stages["migrate_kernel_us"] = timeit(
-                    migrate_k, state, pid, valid, gpu, aidx, ok
-                )
-            state, gpu, aidx, ok, mig_res = migrate(state, pid, valid, gpu, aidx, ok)
-        commit = jax.jit(
-            jax.vmap(
-                lambda st, p, g, a, o, er, ec, mr=None: core._stage_commit(
-                    st, p, g, a, o, er, ec, mr
-                )
-            )
-            if mig_res is None
-            else jax.vmap(core._stage_commit)
-        )
-        args = (state, pid, gpu, aidx, ok, zeros, zeros)
-        if mig_res is not None:
-            args = args + (mig_res,)
-        stages["commit_us"] = timeit(commit, *args)
-        out[policy] = stages
-
-    # queued protocol: attribute the wait/park stages against a
-    # representative above-saturation state (the wait ring actually cycles)
-    qcfg = dataclasses.replace(
-        cfg, protocol="steady-queued", offered_load=max(cfg.offered_load, 1.1)
-    )
-    qevents, _, qrr, qrc = batched.presample_arrivals(qcfg, runs, queued=True)
-    qdev = jax.tree.map(
-        lambda x: None if x is None else jnp.asarray(x), qevents
-    )
-    qcore = batched.EngineCore(
-        spec=resolve("mfi", engine="batched"),
-        protocol=batched.resolve_protocol("steady-queued"),
-        metric=qcfg.metric,
-        tables=tables,
-        midx=midx,
-        vg=vg,
-        wait_patience=qcfg.wait_patience,
-    )
-    qstate, _ = batched._simulate(
-        qdev, policy="mfi", metric=qcfg.metric, num_gpus=qcfg.num_gpus,
-        ring_rows=qrr, ring_cols=qrc, use_kernel=False,
-        protocol="steady-queued", wait_slots=qcfg.wait_capacity,
-        wait_patience=qcfg.wait_patience, midx=midx, tables=tables,
-    )
-    t = jnp.ones((runs,), jnp.int32)
-    wlive = jnp.ones((runs,), bool)
-    pid = jnp.full((runs,), 2, jnp.int32)
-    can = (qstate.wait_pid < 0).any(axis=1)  # park only where a slot is free
-    end = t + 5
-    zeros = jnp.zeros((runs,), jnp.int32)
-    wait = jax.jit(jax.vmap(qcore._stage_wait))
-    park = jax.jit(jax.vmap(qcore._stage_park))
-    out["mfi@steady-queued"] = {
-        "wait_us": timeit(wait, qstate, t, wlive),
-        "park_us": timeit(
-            park, qstate, pid, can, t, end, zeros, zeros, zeros, zeros
-        ),
-    }
-    return out
 
 
 def compare_baseline(payload: dict, baseline_path: str, gate: float = REGRESSION_GATE):
@@ -607,7 +463,7 @@ def bench_point(policy: str, cfg: SimConfig, runs: int, py_runs: int):
 def main(runs: int = 64, num_gpus: int = 100, load: float = 0.85,
          policy: str = "mfi", py_runs: int = 3, smoke: bool = False,
          json_path: str | None = None, sweep: bool | None = None,
-         profile: bool = False, baseline: str | None = None,
+         baseline: str | None = None,
          compile_cache: bool = False, stress: bool = False):
     compile_cache = enable_compile_cache() if compile_cache else None
     if stress:  # memory-bound chunked stress point only (CI runs it under a
@@ -738,13 +594,6 @@ def main(runs: int = 64, num_gpus: int = 100, load: float = 0.85,
         payload["chunked"] = chunked
     if fused is not None:
         payload["fused"] = fused
-    if profile:
-        stage_profile = profile_stages(cfg, runs)
-        payload["stage_profile"] = stage_profile
-        print("table,stage-profile,policy,stage,us_per_event")
-        for name, stages in stage_profile.items():
-            for stage, us in sorted(stages.items()):
-                print(f"profile,batched,{name},{stage.removesuffix('_us')},{us:.1f}")
     gate_ok = True
     if baseline:
         vs, gate_ok = compare_baseline(payload, baseline)
@@ -844,10 +693,6 @@ if __name__ == "__main__":
                     help="per-policy warm throughput over every registered "
                          "batched-capable policy (default: on in smoke mode)")
     ap.add_argument("--no-sweep", dest="sweep", action="store_false")
-    ap.add_argument("--profile", action="store_true",
-                    help="per-stage wall-time breakdown of the EngineCore "
-                         "pipeline (select/migrate/commit/expire) for a "
-                         "defrag and a non-defrag spec")
     ap.add_argument("--baseline", default=None,
                     help="diff against a committed artifact (e.g. "
                          "benchmarks/BENCH_baseline.json); exits non-zero on "
@@ -867,6 +712,6 @@ if __name__ == "__main__":
         runs=args.runs, num_gpus=args.num_gpus, load=args.load,
         policy=args.policy, py_runs=args.py_runs, smoke=args.smoke,
         json_path=args.json_path, sweep=args.sweep,
-        profile=args.profile, baseline=args.baseline,
+        baseline=args.baseline,
         compile_cache=args.compile_cache, stress=args.stress,
     )

@@ -1,0 +1,12 @@
+"""Device milliseconds per call of the scan's ``stage.fault`` ops: GPU failures and recoveries, and the eviction of the failed GPUs' leases.
+
+Self time of the traced call's ops that the program's stage map
+(``repro.obs.stage_map``, from the executable the call ran) gives to this
+stage; the largest over the devices.  Nothing when the program has no
+stage map or the stage did not run."""
+
+from perfbench.lib import program
+
+
+def read(ctx):
+    return (program.stages(ctx) or {}).get("fault")

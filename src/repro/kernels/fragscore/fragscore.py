@@ -126,6 +126,7 @@ def fragscore(
         out_specs=pl.BlockSpec((BLK_M, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, 1), jnp.float32),
         interpret=interpret_mode(interpret),
+        name="fragscore",
     )(occ_p.astype(jnp.float32), w.astype(jnp.float32), v.astype(jnp.float32))
     return out[:m, 0]
 
@@ -188,6 +189,7 @@ def mfi_delta(
         out_specs=pl.BlockSpec((BLK_M, a), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, a), jnp.float32),
         interpret=interpret_mode(interpret),
+        name="mfi_delta",
     )(
         occ_p.astype(jnp.float32),
         w.astype(jnp.float32),
@@ -322,6 +324,7 @@ def delta_from_base(
         out_specs=pl.BlockSpec((BLK_M, a), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, a), jnp.float32),
         interpret=interpret_mode(interpret),
+        name="delta_from_base",
     )(
         base_p,
         free_p,
@@ -571,6 +574,7 @@ def select_from_base(
         out_specs=pl.BlockSpec((None, 1, l + 3), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, 1, l + 3), jnp.float32),
         interpret=interpret_mode(interpret),
+        name="select_from_base",
     )(
         base_p,
         col(free),
@@ -795,6 +799,7 @@ def migrate_refine(
             out_specs=pl.BlockSpec((1, p_, w0), lambda i: (i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((t0, p_, w0), jnp.float32),
             interpret=interpret_mode(interpret),
+        name="migrate_refine",
         )(*class_ops)
         return out0, None
 
@@ -860,5 +865,6 @@ def migrate_refine(
             jax.ShapeDtypeStruct((c_pad, l + 2), jnp.float32),
         ],
         interpret=interpret_mode(interpret),
+        name="migrate_refine",
     )(*class_ops, *victim_ops)
     return out0, out1[:c]

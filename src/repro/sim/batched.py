@@ -164,13 +164,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Dict, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import cluster as jcluster
 from repro.core import mig
 from repro.core.policy import (
@@ -2029,6 +2029,10 @@ class EngineCore:
 
     # -- the composed step ---------------------------------------------------
     def step(self, st: ReplicaState, x):
+        """One event of one replica.  Each stage runs under a named scope,
+        ``stage.<name>`` (:data:`repro.obs.STAGES`), which reaches every op
+        it emits, so a profile's device time can be split by stage
+        (:func:`repro.obs.stage_map`)."""
         if self.protocol.faulted:
             (pid, exp_row, exp_col, drain_row, new_slot,
              t, end, prio, ten, wlive, fail_v, rec_v) = x
@@ -2040,47 +2044,58 @@ class EngineCore:
 
         frag = free_sum = active = None
         if self.protocol.boundary_metrics:
-            frag, free_sum, active = self._stage_boundary_measure(st)
+            with jax.named_scope("stage.measure"):
+                frag, free_sum, active = self._stage_boundary_measure(st)
 
-        st = self._stage_expire(st, drain_row, new_slot)
+        with jax.named_scope("stage.expire"):
+            st = self._stage_expire(st, drain_row, new_slot)
 
         evicted = evict_lost = evict_esum = None
         if self.protocol.faulted:  # after expire: same-slot completions win
-            st, evicted, evict_lost, evict_esum = self._stage_fault(
-                st, fail_v, rec_v, t
-            )
+            with jax.named_scope("stage.fault"):
+                st, evicted, evict_lost, evict_esum = self._stage_fault(
+                    st, fail_v, rec_v, t
+                )
 
         wadm_eidx = wadm_gpu = wadm_aidx = parked = None
         if self.protocol.queued:  # waiting requests admit ahead of the arrival
-            st, wadm_eidx, wadm_gpu, wadm_aidx, ok_w = self._stage_wait(st, t, wlive)
-            wadm_gpu = jnp.where(ok_w, wadm_gpu, -1)
-            wadm_aidx = jnp.where(ok_w, wadm_aidx, -1)
+            with jax.named_scope("stage.queue"):
+                st, wadm_eidx, wadm_gpu, wadm_aidx, ok_w = self._stage_wait(
+                    st, t, wlive
+                )
+                wadm_gpu = jnp.where(ok_w, wadm_gpu, -1)
+                wadm_aidx = jnp.where(ok_w, wadm_aidx, -1)
 
         valid = pid >= 0
         pid_c = jnp.maximum(pid, 0)
-        gpu, aidx, ok = self._stage_select(st, pid_c, valid)
+        with jax.named_scope("stage.select"):
+            gpu, aidx, ok = self._stage_select(st, pid_c, valid)
 
         mig_res = None
         if self.spec.defrag:
-            st, gpu, aidx, ok, mig_res = self._stage_migrate(
-                st, pid_c, valid, gpu, aidx, ok
-            )
+            with jax.named_scope("stage.migrate"):
+                st, gpu, aidx, ok, mig_res = self._stage_migrate(
+                    st, pid_c, valid, gpu, aidx, ok
+                )
 
         meta = (end, prio, ten, st.ev) if self.protocol.faulted else None
-        st = self._stage_commit(
-            st, pid_c, gpu, aidx, ok, exp_row, exp_col, mig_res, meta=meta
-        )
+        with jax.named_scope("stage.commit"):
+            st = self._stage_commit(
+                st, pid_c, gpu, aidx, ok, exp_row, exp_col, mig_res, meta=meta
+            )
 
         if self.protocol.queued:
-            parked = valid & ~ok & wlive & (st.wait_pid < 0).any()
-            st = self._stage_park(
-                st, pid_c, parked, t, end, prio, ten, exp_row, exp_col
-            )
-            st = st._replace(ev=st.ev + 1)
+            with jax.named_scope("stage.queue"):
+                parked = valid & ~ok & wlive & (st.wait_pid < 0).any()
+                st = self._stage_park(
+                    st, pid_c, parked, t, end, prio, ten, exp_row, exp_col
+                )
+                st = st._replace(ev=st.ev + 1)
 
         post_frag = post_free = post_active = None
         if self.protocol.post_metrics:
-            post_frag, post_free, post_active = self._stage_post_measure(st)
+            with jax.named_scope("stage.measure"):
+                post_frag, post_free, post_active = self._stage_post_measure(st)
 
         neg1 = jnp.int32(-1)
         trace = EventTrace(
@@ -2766,7 +2781,6 @@ def simulate_chunked(
     shard: Optional[bool] = None,
     checkpoint_path=None,
     checkpoint_every: int = 0,
-    stats: Optional[dict] = None,
 ) -> Tuple[ReplicaState, EventTrace]:
     """Drive the event scan in chunks with a double-buffered device feed.
 
@@ -2780,7 +2794,7 @@ def simulate_chunked(
       :func:`_scan_chunk` call (in-place buffer reuse);
     * chunk ``k+1`` is ``device_put`` while chunk ``k``'s compute is in
       flight (dispatch is asynchronous), so host→device transfer overlaps
-      compute — the overlapped fraction is reported via ``stats``;
+      compute;
     * with ``stream=True`` (default) each chunk's decision trace is fetched
       back and concatenated host-side, so full traces never accumulate on
       device; ``stream=False`` keeps them on device (explicit opt-in).
@@ -2790,10 +2804,14 @@ def simulate_chunked(
     buffers are donated to the first chunk).  ``checkpoint_path`` +
     ``checkpoint_every`` (in chunks) persist the carry periodically through
     :mod:`repro.checkpoint.ckpt`.  ``shard`` places every staged chunk on
-    the replica-axis mesh (see :func:`_replica_sharding`).  ``stats``, when
-    given, is filled with chunk/transfer telemetry, including
-    ``h2d_overlap_frac`` — the fraction of host→device bytes staged while a
-    chunk compute was in flight (all puts except the first prefetch).
+    the replica-axis mesh (see :func:`_replica_sharding`).
+
+    The run is one ``simulate_chunked`` span of :mod:`repro.obs` (a child
+    of ``run_batched``'s, or a call of its own), holding each chunk's
+    ``transfer``, ``dispatch`` and ``fetch`` spans in the order they ran,
+    and adds to the call's counters ``h2d_bytes`` (every staged chunk),
+    ``h2d_overlapped_bytes`` (those staged while a chunk's scan was in
+    flight: all but the first) and ``d2h_bytes`` (the fetched traces).
     """
     if chunk_size <= 0:
         raise ValueError(f"chunk_size must be positive, got {chunk_size}")
@@ -2819,66 +2837,54 @@ def simulate_chunked(
     host = jax.tree.map(np.asarray, events)  # host slicing source
     bounds = list(range(start, e_max, chunk_size)) + [e_max]
     n_chunks = len(bounds) - 1
-    h2d_s = h2d_overlap_s = d2h_s = 0.0
-    h2d_bytes = h2d_overlap_bytes = 0
+    mesh = None if sharding is None else sharding.mesh
 
-    def put(lo, hi):
+    def put(lo, hi, overlapped):
         ch = jax.tree.map(lambda x: x[lo:hi], host)
         nbytes = sum(x.nbytes for x in jax.tree.leaves(ch))
-        t0 = time.perf_counter()
+        obs.count("h2d_bytes", nbytes)
+        if overlapped:
+            obs.count("h2d_overlapped_bytes", nbytes)
         # one batched transfer for the whole chunk pytree (a single
         # Sharding broadcasts across leaves), not one dispatch per field
-        dev = (
-            jax.device_put(ch, sharding) if sharding is not None
-            else jax.device_put(ch)
-        )
-        return dev, time.perf_counter() - t0, nbytes
+        with obs.span("transfer"):
+            return (
+                jax.device_put(ch, sharding) if sharding is not None
+                else jax.device_put(ch)
+            )
 
-    buf, dt, nb = put(bounds[0], bounds[1])  # prefetch chunk 0 (not overlapped)
-    h2d_s += dt
-    h2d_bytes += nb
-    mesh = None if sharding is None else sharding.mesh
-    state, tr = _scan_chunk(state, buf, mesh=mesh, **statics)  # async dispatch
-    traces = []
-    for k in range(n_chunks):
-        # chunk k's scan is already in flight; ``state`` is its output carry
-        if checkpoint_path and checkpoint_every and (k + 1) % checkpoint_every == 0:
-            # copy the post-chunk-k carry out *before* the next dispatch
-            # donates its buffers (a deliberate pipeline bubble)
-            save_stream_checkpoint(checkpoint_path, state, bounds[k + 1])
-        if k + 1 < n_chunks:
-            # stage chunk k+1 and dispatch its scan before blocking on
-            # chunk k's trace, so the d2h fetch below overlaps compute
-            buf, dt, nb = put(bounds[k + 1], bounds[k + 2])
-            h2d_s += dt
-            h2d_bytes += nb
-            h2d_overlap_s += dt
-            h2d_overlap_bytes += nb
-            state, tr_next = _scan_chunk(state, buf, mesh=mesh, **statics)
-        if stream:
-            t0 = time.perf_counter()
-            traces.append(jax.device_get(tr))  # joins chunk k's compute
-            d2h_s += time.perf_counter() - t0
-        else:
+    def scan(state, buf):
+        with obs.span("dispatch"):
+            return _scan_chunk(state, buf, mesh=mesh, **statics)
+
+    with obs.span("simulate_chunked"):
+        buf = put(bounds[0], bounds[1], False)  # prefetch chunk 0
+        state, tr = scan(state, buf)  # async dispatch
+        traces = []
+        for k in range(n_chunks):
+            # chunk k's scan is already in flight; ``state`` is its output carry
+            if checkpoint_path and checkpoint_every and (k + 1) % checkpoint_every == 0:
+                # copy the post-chunk-k carry out *before* the next dispatch
+                # donates its buffers (a deliberate pipeline bubble)
+                save_stream_checkpoint(checkpoint_path, state, bounds[k + 1])
+            if k + 1 < n_chunks:
+                # stage chunk k+1 and dispatch its scan before blocking on
+                # chunk k's trace, so the d2h fetch below overlaps compute
+                buf = put(bounds[k + 1], bounds[k + 2], True)
+                state, tr_next = scan(state, buf)
+            if stream:
+                with obs.span("fetch"):
+                    tr = jax.device_get(tr)  # joins chunk k's compute
+                obs.count("d2h_bytes", _nbytes(tr))
             traces.append(tr)
-        if k + 1 < n_chunks:
-            tr = tr_next
-    if stats is not None:
-        stats.update(
-            chunks=n_chunks,
-            chunk_size=chunk_size,
-            events=e_max - start,
-            h2d_seconds=h2d_s,
-            h2d_overlapped_seconds=h2d_overlap_s,
-            h2d_bytes=h2d_bytes,
-            h2d_overlapped_bytes=h2d_overlap_bytes,
-            h2d_overlap_frac=(
-                h2d_overlap_bytes / h2d_bytes if h2d_bytes else 0.0
-            ),
-            d2h_seconds=d2h_s,
-        )
+            if k + 1 < n_chunks:
+                tr = tr_next
     concat = np.concatenate if stream else jnp.concatenate
     return state, _concat_traces(traces, concat)
+
+
+def _nbytes(tree) -> int:
+    return sum(x.nbytes for x in jax.tree.leaves(tree))
 
 
 class BatchedProgram(NamedTuple):
@@ -2897,6 +2903,24 @@ class BatchedProgram(NamedTuple):
     protocol: Protocol
     spec: mig.ClusterSpec
     cfg: SimConfig
+
+    def launch(self, shard: Optional[bool] = None):
+        """The monolithic scan's arguments as :func:`run_batched` passes
+        them: ``(events, kwargs)``, the stream on the device (split over
+        the replica mesh when ``shard`` allows, :func:`shard_events`) and
+        the statics with that ``mesh``.  Call ``_simulate(events,
+        **kwargs)``."""
+        runs = self.events.pid.shape[1]
+        sharding = _replica_sharding(runs, shard)
+        events = shard_events(jax.tree.map(jnp.asarray, self.events), runs, shard)
+        return events, dict(
+            self.kwargs, mesh=None if sharding is None else sharding.mesh
+        )
+
+    def lower(self, shard: Optional[bool] = None):
+        """The monolithic scan :func:`run_batched` launches, lowered."""
+        events, kwargs = self.launch(shard)
+        return _simulate.lower(events, **kwargs)
 
     def aggregate(self, trace: EventTrace) -> Dict[str, float]:
         """The protocol's ``run_many``-keyed aggregates of a host trace."""
@@ -2920,7 +2944,32 @@ def batched_program(
 
     ``use_kernel=None`` picks the Pallas lowering on TPU for every spec
     that allows it (``PolicySpec.kernel_lowering``); see :func:`run_batched`.
+    Runs as the ``presample`` span of :mod:`repro.obs` and counts the
+    stream's lanes (:func:`_count_lanes`).
     """
+    with obs.span("presample"):
+        prog = _batched_program(policy, cfg, runs, use_kernel)
+    _count_lanes(prog.events, prog.meta)
+    return prog
+
+
+def _count_lanes(events: EventStream, meta: EventMeta) -> None:
+    """The open call's lane counters: ``lanes`` (``E_max × R``, what the
+    scan steps over), ``arrival_lanes``, ``heartbeat_lanes`` (a slot with
+    no arrival) and ``padding_lanes`` (each replica's trailing sentinel and
+    the padding to the longest replica, at the slot past the last)."""
+    arrivals = events.pid >= 0
+    tail = ~arrivals & (meta.slot >= meta.slot.max())
+    obs.count("lanes", events.pid.size)
+    obs.count("arrival_lanes", arrivals.sum())
+    obs.count("padding_lanes", tail.sum())
+    obs.count("heartbeat_lanes", events.pid.size - arrivals.sum() - tail.sum())
+
+
+def _batched_program(
+    policy: PolicyLike, cfg: SimConfig, runs: int, use_kernel: bool | None
+) -> BatchedProgram:
+    """:func:`batched_program`'s validation and presampling."""
     policy = resolve(policy, engine="batched")
     proto = resolve_protocol(cfg.protocol)
     spec = cfg.spec()
@@ -2978,7 +3027,6 @@ def run_batched(
     shard: Optional[bool] = None,
     chunk_size: Optional[int] = None,
     stream: Optional[bool] = None,
-    stats: Optional[dict] = None,
 ) -> Dict[str, float]:
     """Average ``runs`` replicas in one device program.
 
@@ -3002,36 +3050,49 @@ def run_batched(
     staged event chunks instead of the full ``(E_max, R)`` tensors —
     bit-identical results for any chunk size.  ``stream`` (chunked only;
     default ``True``) fetches each chunk's trace back as it completes so
-    traces never accumulate on device; ``stats`` (chunked only) receives
-    transfer/overlap telemetry.  ``chunk_size=None`` (default) keeps
-    today's single-chunk monolithic scan.
+    traces never accumulate on device.  ``chunk_size=None`` (default)
+    keeps today's single-chunk monolithic scan.
+
+    Each call leaves one ``run_batched`` call in :mod:`repro.obs`'s record:
+    its phases as spans (``presample``, ``transfer``, ``dispatch``,
+    ``scan``, ``fetch``, ``aggregate``; chunked: one ``simulate_chunked``
+    span in place of the middle four) and its counters (the stream's
+    lanes, ``h2d_bytes``, ``d2h_bytes``).
     """
     if chunk_size is not None and chunk_size <= 0:
         raise ValueError(f"chunk_size must be positive, got {chunk_size}")
-    if chunk_size is None and (stream is not None or stats is not None):
-        raise ValueError(
-            "stream/stats are chunked-driver knobs; pass chunk_size as well"
-        )
-    prog = batched_program(policy, cfg, runs, use_kernel)
-    events, common = prog.events, prog.kwargs
-    if chunk_size is not None:
-        _, trace = simulate_chunked(
-            events,
-            chunk_size=chunk_size,
-            stream=True if stream is None else stream,
-            shard=shard,
-            stats=stats,
-            **common,
-        )
-        trace = jax.device_get(trace)  # no-op for already-streamed traces
-    else:
-        sharding = _replica_sharding(runs, shard)
-        events_dev = shard_events(jax.tree.map(jnp.asarray, events), runs, shard)
-        _, trace = jax.device_get(_simulate(
-            events_dev, mesh=None if sharding is None else sharding.mesh,
-            **common,
-        ))
-    return prog.aggregate(trace)
+    if chunk_size is None and stream is not None:
+        raise ValueError("stream is a knob of the chunked scan; pass chunk_size as well")
+    with obs.span("run_batched"):
+        prog = batched_program(policy, cfg, runs, use_kernel)
+        if chunk_size is not None:
+            stream = True if stream is None else stream
+            _, trace = simulate_chunked(
+                prog.events, chunk_size=chunk_size, stream=stream, shard=shard,
+                **prog.kwargs,
+            )
+            if not stream:
+                with obs.span("fetch"):
+                    trace = jax.device_get(trace)
+                obs.count("d2h_bytes", _nbytes(trace))
+        else:
+            with obs.span("transfer"):
+                events, kwargs = prog.launch(shard)
+            obs.count("h2d_bytes", _nbytes(prog.events))
+            with obs.span("dispatch"):
+                out = _simulate(events, **kwargs)
+                # queue the copies back now, as ``device_get`` would: they
+                # then start the moment the scan ends, with no host round trip
+                for x in jax.tree.leaves(out):
+                    if isinstance(x, jax.Array):
+                        x.copy_to_host_async()
+            with obs.span("scan"):
+                jax.block_until_ready(out)
+            with obs.span("fetch"):
+                _, trace = jax.device_get(out)
+            obs.count("d2h_bytes", _nbytes(out))
+        with obs.span("aggregate"):
+            return prog.aggregate(trace)
 
 
 def aggregate(

@@ -362,8 +362,38 @@ class TestChunkedErrorPaths:
         cfg = SimConfig(num_gpus=3, offered_load=1.0, seed=1)
         with pytest.raises(ValueError, match="chunk_size"):
             batched.run_batched("mfi", cfg, runs=2, stream=True)
-        with pytest.raises(ValueError, match="chunk_size"):
-            batched.run_batched("mfi", cfg, runs=2, stats={})
+
+    def test_chunked_record_spans_and_bytes(self):
+        """A chunked call's record: each chunk's transfer, dispatch and fetch
+        in the double-buffered order, and byte counters equal to the bytes
+        of the staged chunks and of the fetched traces."""
+        from repro import obs
+
+        cfg, events, rr, rc = self._stream()
+        e_max = events.pid.shape[0]
+        chunk = e_max // 3 + 1  # three chunks, the last one ragged
+        _, trace = batched.simulate_chunked(
+            events, chunk_size=chunk, ring_rows=rr, ring_cols=rc,
+            **_statics(cfg, "mfi"),
+        )
+        call = obs.calls()[-1]
+        assert call.root.name == "simulate_chunked"
+        kids = [s.name for s in call.spans if s.parent == 0]
+        assert kids == ["transfer", "dispatch", "transfer", "dispatch", "fetch",
+                        "transfer", "dispatch", "fetch", "fetch"]
+        starts = [s.start_ns for s in call.spans]
+        assert starts == sorted(starts)
+        assert all(s.start_ns <= s.end_ns for s in call.spans)
+        host = jax.tree.map(np.asarray, events)
+        per_chunk = [
+            sum(x[lo:lo + chunk].nbytes for x in jax.tree.leaves(host))
+            for lo in range(0, e_max, chunk)
+        ]
+        assert call.counters["h2d_bytes"] == sum(per_chunk)
+        assert call.counters["h2d_overlapped_bytes"] == sum(per_chunk[1:])
+        assert call.counters["d2h_bytes"] == sum(
+            x.nbytes for x in jax.tree.leaves(trace)
+        )
 
     def test_api_python_engine_rejects_chunk_size(self):
         with pytest.raises(ValueError, match="batched"):

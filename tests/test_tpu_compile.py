@@ -17,6 +17,7 @@ entry compiled for a described chip cannot be read back without one.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +123,10 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, m):
     fn, shapes = _kernel_program(kernel, m, one_chip)
     text = _compile(fn, *shapes).as_text()
     assert "tpu_custom_call" in text, f"{kernel}: no Mosaic kernel in the program"
+    # a profile names the kernel's op after its pallas_call, which the
+    # benchmark's kernel metrics match by name
+    name = kernel.split(":")[0]
+    assert re.search(rf"^\s*(ROOT )?%{name}\.\d+ = .* custom-call\(", text, re.M)
 
 
 def test_jnp_scan_compiles_for_v5e(one_chip):
