@@ -1653,6 +1653,51 @@ def _init_state(
     )
 
 
+#: replicas per device from which a TPU drains the expiry ring by the one-hot
+#: form (measured on a v5e: the row gather is cheaper at 8, the one-hot form
+#: at 64 and 500; the crossover between is unmeasured)
+ONEHOT_DRAIN_REPLICAS = 64
+
+
+def ring_drain_onehot(replicas: int) -> bool:
+    """Whether the expire stage drains its ring row by a one-hot over the
+    rows — the one place the choice is made, from the backend the scan
+    compiles for (as :func:`repro.kernels.interpret_mode` picks Mosaic) and
+    the ``replicas`` per device its step is vmapped over.
+
+    On a TPU the vmapped carry keeps each ring plane in the layout the
+    commit stage's one-entry scatter updates in place; a vmapped row gather
+    or scatter wants a layout padded over the ring's columns instead, and
+    XLA copies the whole plane into it and back every event.  The one-hot
+    form reads and clears the row in the carry's own layout, at the price
+    of one pass over the whole plane: cheaper from tens of replicas on, not
+    at a handful.  On the CPU the row gather is the cheaper form.
+    """
+    return jax.default_backend() == "tpu" and replicas >= ONEHOT_DRAIN_REPLICAS
+
+
+def _drain_ring_row(plane: jax.Array, row, clear=None, *, onehot: bool):
+    """Row ``row`` of a ring plane ``(K+2, E, ...)`` and the plane after it.
+
+    With ``clear`` (int32 0/1) the row read is multiplied by it and the
+    returned plane has that row multiplied by ``1 - clear``; without, the
+    plane comes back as it was.  ``onehot`` picks the form
+    (:func:`ring_drain_onehot`); both are integer ops with bit-identical
+    results.
+    """
+    if onehot:
+        hit = jnp.arange(plane.shape[0]) == row
+        sel = hit.astype(plane.dtype).reshape((-1,) + (1,) * (plane.ndim - 1))
+        if clear is not None:
+            sel = sel * clear
+        got = (plane * sel).sum(axis=0, dtype=plane.dtype)
+        return got, plane if clear is None else plane * (1 - sel)
+    got = plane[row]
+    if clear is None:
+        return got, plane
+    return got * clear, plane.at[row].set(got * (1 - clear))
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineCore:
     """The staged scan body: one event step, composed from stages.
@@ -1677,6 +1722,7 @@ class EngineCore:
     select_fn: Optional[object] = None
     migrate_fn: Optional[object] = None
     wait_patience: int = 0  # queued protocols: max slots a request may wait
+    drain_onehot: bool = False  # expire stage's ring drain form (ring_drain_onehot)
 
     # -- stages --------------------------------------------------------------
     def _stage_boundary_measure(self, st: ReplicaState):
@@ -1690,8 +1736,9 @@ class EngineCore:
     def _stage_expire(self, st: ReplicaState, drain_row, new_slot):
         """Drain this slot's expiry-ring row (first event of the slot only)."""
         ns = new_slot.astype(jnp.int32)
-        rel_gpu = st.ring_gpu[drain_row]  # (E,)
-        rel_mask = st.ring_mask[drain_row] * ns  # (E, S)
+        drain = functools.partial(_drain_ring_row, onehot=self.drain_onehot)
+        rel_gpu, _ = drain(st.ring_gpu, drain_row)  # (E,)
+        rel_mask, ring_mask = drain(st.ring_mask, drain_row, ns)  # (E, S)
         occ = None if st.occ is None else st.occ.at[rel_gpu].add(-rel_mask)
         rel_win = jnp.einsum(
             "es,ens->en", rel_mask.astype(jnp.float32), self.tables.W[self.midx[rel_gpu]]
@@ -1707,7 +1754,6 @@ class EngineCore:
                 base[rel_gpu], free[rel_gpu], self.metric, self.vg[rel_gpu]
             )
         )
-        ring_mask = st.ring_mask.at[drain_row].set(st.ring_mask[drain_row] * (1 - ns))
         return st._replace(
             occ=occ, base=base, free=free, f=f, ring_mask=ring_mask
         )
@@ -2144,6 +2190,7 @@ def _build_core(
     wait_patience: int = 0,
     midx: Optional[jax.Array] = None,
     tables: Optional[SpecTables] = None,
+    replicas: int = 0,
 ) -> Tuple[EngineCore, SpecTables, jax.Array]:
     """Validate one engine configuration and build its staged core.
 
@@ -2152,6 +2199,8 @@ def _build_core(
     point applies the same policy/protocol validation and compiles the same
     stages, so the chunked and monolithic drivers cannot drift.  Returns
     ``(core, tables, midx)`` with the homogeneous defaults filled in.
+    ``replicas`` is how many replicas per device the step is vmapped over
+    (:func:`ring_drain_onehot`).
     """
     pspec = resolve(policy, engine="batched")
     proto = resolve_protocol(protocol)
@@ -2200,7 +2249,7 @@ def _build_core(
         spec=pspec, protocol=proto, metric=metric, tables=tables,
         midx=midx, vg=vg, frag_fn=frag_fn, delta_fn=delta_fn,
         select_fn=select_fn, migrate_fn=migrate_fn,
-        wait_patience=wait_patience,
+        wait_patience=wait_patience, drain_onehot=ring_drain_onehot(replicas),
     )
     return core, tables, midx
 
@@ -2283,14 +2332,14 @@ def _simulate(
     :func:`_replica_sharding`) runs it per device (:func:`_per_device`)."""
 
     def scan(events, midx, tables):
+        runs = events.pid.shape[1]
         core, tables, midx = _build_core(
             policy=policy, metric=metric, num_gpus=num_gpus,
             use_kernel=use_kernel, kernel_spec=kernel_spec, protocol=protocol,
             wait_slots=wait_slots, wait_patience=wait_patience,
-            midx=midx, tables=tables,
+            midx=midx, tables=tables, replicas=runs,
         )
         step = jax.vmap(core.step, in_axes=(0, 0))
-        runs = events.pid.shape[1]
         init = _broadcast_init(core, runs, ring_rows, ring_cols, wait_slots)
         return jax.lax.scan(
             lambda st, x: step(st, x), init, _scan_xs(events, core.protocol)
@@ -2701,7 +2750,7 @@ def _scan_chunk(
             policy=policy, metric=metric, num_gpus=num_gpus,
             use_kernel=use_kernel, kernel_spec=kernel_spec, protocol=protocol,
             wait_slots=wait_slots, wait_patience=wait_patience,
-            midx=midx, tables=tables,
+            midx=midx, tables=tables, replicas=events.pid.shape[1],
         )
         step = jax.vmap(core.step, in_axes=(0, 0))
         return jax.lax.scan(

@@ -187,3 +187,71 @@ def _compile_sharded(topo):
     assert "tpu_custom_call" in text
     with pytest.raises(NotImplementedError, match="shard_map"):
         batched._simulate.lower(shapes, **prog.kwargs).compile()
+
+
+def _loop_copies(text):
+    """``(name, shape)`` of every copy in the scan's ``while`` body and the
+    computations it calls (fusion bodies hold no copies of their own)."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    todo = re.findall(r"body=%?([\w.\-]+)", text)
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(
+                r"(?:to_apply|body|condition|branch_computations)=\{?%?([\w.\-]+)",
+                line,
+            )
+    return [
+        m.groups()
+        for name in seen
+        for m in re.finditer(
+            r"^\s*(?:ROOT )?%(copy[\w.]*) = (\w+\[[\d,]*\])", "\n".join(comps[name]), re.M
+        )
+    ]
+
+
+def test_kernel_scan_drains_ring_in_carry_layout(one_chip, monkeypatch):
+    """The steady mfi kernel scan keeps its ring planes in the carry's
+    layout: no copy of ``ring_mask`` or ``ring_gpu`` inside the loop.
+
+    A vmapped row gather/scatter of the ring wants a layout padded over the
+    ring's columns and made XLA copy the whole plane there and back every
+    event; the chip's drain form (:func:`batched.ring_drain_onehot`, from
+    ``ONEHOT_DRAIN_REPLICAS`` replicas a device on) reads and clears the row
+    in place.  The engine picks that form, and Mosaic, from the backend,
+    which is the CPU here, so the test steers both (and clears the jit
+    caches on both sides, as above).
+    """
+    jax.clear_caches()
+    monkeypatch.setattr(fragscore, "interpret_mode", lambda interpret=None: False)
+    monkeypatch.setattr(
+        batched, "ring_drain_onehot",
+        lambda replicas: replicas >= batched.ONEHOT_DRAIN_REPLICAS,
+    )
+    try:
+        prog = batched.batched_program(
+            "mfi", SimConfig(num_gpus=100, seed=0), R, use_kernel=True
+        )
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            prog.events,
+        )
+        text = batched._simulate.lower(shapes, **prog.kwargs).compile().as_text()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    rows, cols = prog.kwargs["ring_rows"], prog.kwargs["ring_cols"]
+    ring = {f"s32[{R},{rows},{cols},8]", f"s32[{R},{rows},{cols}]"}
+    copies = _loop_copies(text)
+    assert copies, "no copy found in the loop: the parse missed the while body"
+    assert [c for c in copies if c[1] in ring] == []
