@@ -365,6 +365,13 @@ def _blk_rows(m: int, cap: int = BLK_M) -> int:
     return min(cap, -(-m // 8) * 8)
 
 
+def padded_rows(m: int) -> int:
+    """Rows a fused launch over ``m`` GPUs covers: ``m`` rounded up to a
+    whole number of its row tiles (:func:`_blk_rows`)."""
+    blk = _blk_rows(m)
+    return -(-m // blk) * blk
+
+
 def _key_tile(base_key, sign, delta, free, mem, gid, anchors, shape):
     """One effective scoring key as a (blk, A) tile (direction applied).
 
@@ -545,7 +552,7 @@ def select_from_base(
     m, n = base.shape
     a = mw.shape[0]
     blk = _blk_rows(m)
-    m_pad = -(-m // blk) * blk
+    m_pad = padded_rows(m)
     t = m_pad // blk
     base_p = jnp.zeros((m_pad, n), jnp.float32).at[:m].set(base)
     col = lambda x: jnp.zeros((m_pad, 1), jnp.float32).at[:m, 0].set(  # noqa: E731

@@ -29,6 +29,13 @@ under ``jax.named_scope("stage.<name>")``; :func:`stage_map` reads, from
 the compiled text of the very executable ``run_batched`` launches, which
 stage owns each HLO instruction.  A profile's device ops are named after
 those instructions, so the map turns a trace's op times into stage times.
+Inside the stages, two sub-scopes mark work that cuts across them
+(:data:`SCOPES`): ``rescore``, the rescoring of the GPUs an event touched
+(expire, commit, a migration's landing GPU), and ``dispatch``, the
+per-model-group gathers, padding, scatters and merge around the select,
+delta and migrate kernels.  :func:`scope_map` reads them from the same
+compiled text; they are not ``stage.*`` scopes, so the stage map is blind
+to them.
 """
 
 from __future__ import annotations
@@ -52,6 +59,8 @@ STAGES = ("measure", "expire", "fault", "queue", "select", "migrate", "commit")
 #: what an op outside every stage scope is attributed to: the loop's own
 #: carry copies and control, the step's decode and trace assembly
 UNSTAGED = "none"
+#: the sub-scopes :func:`scope_map` reads (an op outside them: :data:`UNSTAGED`)
+SCOPES = ("rescore", "dispatch")
 
 
 @dataclasses.dataclass
@@ -138,12 +147,20 @@ _INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _FUSION_CALLS = re.compile(r"\bfusion\(.*\bcalls=%?([\w.\-]+)")
 _STAGE = re.compile(r"\bstage\.(\w+)")
+_SCOPE = re.compile(r"(?<![\w.])(" + "|".join(SCOPES) + r")(?![\w.])")
 
 
 def _stage_of(op_name: str) -> str:
     """The innermost ``stage.*`` scope of an op's ``op_name``, else
     :data:`UNSTAGED`."""
     found = _STAGE.findall(op_name)
+    return found[-1] if found else UNSTAGED
+
+
+def _scope_of(op_name: str) -> str:
+    """The innermost of :data:`SCOPES` in an op's ``op_name``, else
+    :data:`UNSTAGED`."""
+    found = _SCOPE.findall(op_name)
     return found[-1] if found else UNSTAGED
 
 
@@ -160,6 +177,20 @@ def stages_of_text(hlo: str) -> Dict[str, str]:
     ``op_name``'s.  Instructions with no stage scope, such as the loop's
     carry copies or the ``while`` op itself, map to :data:`UNSTAGED`.
     """
+    return _labels_of_text(hlo, _stage_of)
+
+
+def scopes_of_text(hlo: str) -> Dict[str, str]:
+    """``{instruction name: sub-scope}``: the innermost of :data:`SCOPES`
+    that an instruction's ``op_name`` names, else :data:`UNSTAGED`; fusions
+    as in :func:`stages_of_text`."""
+    return _labels_of_text(hlo, _scope_of)
+
+
+def _labels_of_text(hlo: str, label_of) -> Dict[str, str]:
+    """``{instruction name: label_of(op_name)}``, a fusion labelled by its
+    root, else by its last fused instruction with a label other than
+    :data:`UNSTAGED`, else by its own ``op_name``."""
     names: List[str] = []
     op_name: Dict[str, str] = {}
     body: Dict[str, List[str]] = {}  # computation -> its instructions
@@ -188,9 +219,9 @@ def stages_of_text(hlo: str) -> Dict[str, str]:
     for name in names:
         comp = fused.get(name)
         inner = [] if comp is None else [root_of.get(comp, "")] + body.get(comp, [])[::-1]
-        staged = (_stage_of(op_name.get(i, "")) for i in inner)
-        stage = next((st for st in staged if st != UNSTAGED), None)
-        out[name] = stage or _stage_of(op_name.get(name, ""))
+        labels = (label_of(op_name.get(i, "")) for i in inner)
+        label = next((lb for lb in labels if lb != UNSTAGED), None)
+        out[name] = label or label_of(op_name.get(name, ""))
     return out
 
 
@@ -207,3 +238,11 @@ def stage_map(prog, shard: Optional[bool] = None) -> Dict[str, str]:
     to a stage or to :data:`UNSTAGED`.
     """
     return stages_of_text(prog.lower(shard).compile().as_text())
+
+
+def scope_map(prog, shard: Optional[bool] = None) -> Dict[str, str]:
+    """``{HLO instruction name: sub-scope}`` (:data:`SCOPES`, else
+    :data:`UNSTAGED`) of the same executable as :func:`stage_map`, read from
+    the same compiled text: each op of a trace falls in the innermost
+    ``rescore`` or ``dispatch`` scope around it, or in neither."""
+    return scopes_of_text(prog.lower(shard).compile().as_text())

@@ -475,6 +475,7 @@ def make_delta_fn(
     groups = spec.model_groups()  # static (model, numpy GPU-id array) pairs
     a = int(tables.profile_rows.shape[-1])
 
+    @jax.named_scope("dispatch")
     def delta_fn(base, free, f, pid):
         out = jnp.zeros((base.shape[0], a), jnp.float32)
         for k, (_, rows) in enumerate(groups):
@@ -558,6 +559,7 @@ def make_select_fn(
     l = len(keys)
     arange_n = jnp.arange(int(tables.V.shape[-1]), dtype=jnp.int32)
 
+    @jax.named_scope("dispatch")
     def select_fn(base, free, f, pid):
         cand = []
         for k, (_, rows) in enumerate(groups):
@@ -649,6 +651,7 @@ def make_migrate_fn(
         tables.profile_rows[:, :, None, :] == arange_n[None, None, :, None]
     ).astype(jnp.float32)
 
+    @jax.named_scope("dispatch")
     def migrate_fn(base, free, f, base2, free2, f2, rg, rp, kc):
         victims = (
             base2, free2, f2, rg.astype(jnp.float32),
@@ -1745,18 +1748,25 @@ class EngineCore:
         )  # (E, N) — window counts each release frees, per its GPU's model
         base = st.base.at[rel_gpu].add(-rel_win)
         free = st.free.at[rel_gpu].add(rel_mask.sum(axis=1))
-        # rescore exactly the touched rows — through the Pallas kernel when it
-        # is routed in (occ is materialized then), else from the window counts
-        f = st.f.at[rel_gpu].set(
-            self.frag_fn(occ[rel_gpu])
-            if self.frag_fn is not None
-            else _frag_from_base(
-                base[rel_gpu], free[rel_gpu], self.metric, self.vg[rel_gpu]
-            )
-        )
+        f = self._rescore(st.f, rel_gpu, occ, base, free)  # exactly the touched rows
         return st._replace(
             occ=occ, base=base, free=free, f=f, ring_mask=ring_mask
         )
+
+    def _rescore(self, f, g, occ, base, free):
+        """``f`` with GPU ``g`` (one id, or a vector of ids) rescored: through
+        the Pallas kernel when it is routed in (occ is materialized then),
+        else from the window counts.  Runs under the ``rescore`` scope
+        (:func:`repro.obs.scope_map`)."""
+        one = jnp.ndim(g) == 0
+        rows = (lambda x: x[g][None]) if one else (lambda x: x[g])
+        with jax.named_scope("rescore"):
+            new = (
+                self.frag_fn(rows(occ))
+                if self.frag_fn is not None
+                else _frag_from_base(rows(base), rows(free), self.metric, rows(self.vg))
+            )
+            return f.at[g].set(new[0] if one else new)
 
     def _btable(self):
         """Static backoff lookup: ``btable[k]`` is the wait before becoming
@@ -1892,7 +1902,7 @@ class EngineCore:
         ``meta`` (faulted protocols: ``(end, prio, ten, eidx)``) writes the
         entry's identity into the extra ring planes so a later eviction can
         re-queue it losslessly; ``None`` compiles those writes out."""
-        tables, midx, vg = self.tables, self.midx, self.vg
+        tables, midx = self.tables, self.midx
         oki = ok.astype(jnp.int32)
         gpu_c = jnp.where(ok, gpu, 0).astype(jnp.int32)
         kg = midx[gpu_c]  # chosen GPU's model index
@@ -1901,23 +1911,11 @@ class EngineCore:
         occ = None if st.occ is None else st.occ.at[gpu_c].add(mask)
         base = st.base.at[gpu_c].add(mwin)
         free = st.free.at[gpu_c].add(-mask.sum())
-        f = st.f.at[gpu_c].set(
-            self.frag_fn(occ[gpu_c][None])[0]
-            if self.frag_fn is not None
-            else _frag_from_base(
-                base[gpu_c][None], free[gpu_c][None], self.metric, vg[gpu_c][None]
-            )[0]
-        )
+        f = self._rescore(st.f, gpu_c, occ, base, free)
         if mig_res is not None:
             # rescore the victim's landing GPU too (its old GPU is gpu_c)
             g2 = jnp.where(mig_res.mig, mig_res.new_gpu, gpu_c)
-            f = f.at[g2].set(
-                self.frag_fn(occ[g2][None])[0]
-                if self.frag_fn is not None
-                else _frag_from_base(
-                    base[g2][None], free[g2][None], self.metric, vg[g2][None]
-                )[0]
-            )
+            f = self._rescore(f, g2, occ, base, free)
         rr = st.rr
         if self.spec.stateful_cursor:  # advance the cursor past the chosen GPU
             rr = jnp.where(ok, (gpu_c + 1) % midx.shape[0], rr).astype(jnp.int32)
@@ -2236,11 +2234,10 @@ def _build_core(
         kspec = kernel_spec if kernel_spec is not None else _default_spec(num_gpus)
         if kspec.is_homogeneous:
             frag_fn = make_frag_fn(metric, True, kspec.models[0])
-        if pspec.requires_delta_f:
+        fused, delta = _select_kernels(pspec, proto)
+        if delta:
             delta_fn = make_delta_fn(kspec, metric)
-        # the fused select kernel cannot see the faulted protocol's up-mask,
-        # so faulted runs keep the jnp lowering (frag/ΔF kernels still apply)
-        if pspec.fused_argmin and not proto.faulted:
+        if fused:
             select_fn = make_select_fn(kspec, pspec, metric)
             if pspec.defrag:
                 migrate_fn = make_migrate_fn(kspec, pspec, metric)
@@ -2252,6 +2249,14 @@ def _build_core(
         wait_patience=wait_patience, drain_onehot=ring_drain_onehot(replicas),
     )
     return core, tables, midx
+
+
+def _select_kernels(pspec: PolicySpec, proto: Protocol) -> Tuple[bool, bool]:
+    """``(fused, delta)``: whether the kernel path compiles the fused select
+    (:func:`make_select_fn`) and the ΔF kernel (:func:`make_delta_fn`) in.
+    The fused select kernel cannot see the faulted protocol's up-mask, so
+    faulted runs keep the jnp lowering (frag/ΔF kernels still apply)."""
+    return pspec.fused_argmin and not proto.faulted, pspec.requires_delta_f
 
 
 def _broadcast_init(
@@ -2994,11 +2999,13 @@ def batched_program(
     ``use_kernel=None`` picks the Pallas lowering on TPU for every spec
     that allows it (``PolicySpec.kernel_lowering``); see :func:`run_batched`.
     Runs as the ``presample`` span of :mod:`repro.obs` and counts the
-    stream's lanes (:func:`_count_lanes`).
+    stream's lanes (:func:`_count_lanes`) and the select stage's kernel
+    launches (:func:`_count_dispatch`).
     """
     with obs.span("presample"):
         prog = _batched_program(policy, cfg, runs, use_kernel)
     _count_lanes(prog.events, prog.meta)
+    _count_dispatch(prog)
     return prog
 
 
@@ -3013,6 +3020,21 @@ def _count_lanes(events: EventStream, meta: EventMeta) -> None:
     obs.count("arrival_lanes", arrivals.sum())
     obs.count("padding_lanes", tail.sum())
     obs.count("heartbeat_lanes", events.pid.size - arrivals.sum() - tail.sum())
+
+
+def _count_dispatch(prog: BatchedProgram) -> None:
+    """The open call's per-model dispatch counters: ``model_groups``, the
+    kernel launches each select step makes (one per distinct device model
+    when the select stage runs a Pallas kernel, :func:`make_select_fn` or
+    :func:`make_delta_fn`; none on the jnp lowering), and ``kernel_rows``,
+    the GPU rows those launches cover, each group padded to the kernel's
+    row tile."""
+    from repro.kernels.fragscore import fragscore as _k
+
+    launches = any(_select_kernels(prog.kwargs["policy"], prog.protocol))
+    groups = prog.spec.model_groups() if prog.kwargs["use_kernel"] and launches else []
+    obs.count("model_groups", len(groups))
+    obs.count("kernel_rows", sum(_k.padded_rows(len(rows)) for _, rows in groups))
 
 
 def _batched_program(
@@ -3106,7 +3128,8 @@ def run_batched(
     its phases as spans (``presample``, ``transfer``, ``dispatch``,
     ``scan``, ``fetch``, ``aggregate``; chunked: one ``simulate_chunked``
     span in place of the middle four) and its counters (the stream's
-    lanes, ``h2d_bytes``, ``d2h_bytes``).
+    lanes, the select stage's ``model_groups`` and ``kernel_rows``,
+    ``h2d_bytes``, ``d2h_bytes``).
     """
     if chunk_size is not None and chunk_size <= 0:
         raise ValueError(f"chunk_size must be positive, got {chunk_size}")

@@ -255,3 +255,41 @@ def test_kernel_scan_drains_ring_in_carry_layout(one_chip, monkeypatch):
     copies = _loop_copies(text)
     assert copies, "no copy found in the loop: the parse missed the while body"
     assert [c for c in copies if c[1] in ring] == []
+
+
+def test_mixed_fleet_kernel_scan_compiles_for_v5e(one_chip, monkeypatch):
+    """The steady mfi kernel scan on the benchmark's mixed fleet (30
+    A100-80GB, 30 A100-40GB, 20 H100-96GB, 20 H100-80GB), 64 replicas: one
+    ``select_from_base`` launch per device model in the loop body, and no
+    ``fragscore`` kernel (a mixed fleet rescores from the window counts).
+    Mosaic is steered as above."""
+    jax.clear_caches()
+    monkeypatch.setattr(fragscore, "interpret_mode", lambda interpret=None: False)
+    spec = mig.ClusterSpec.parse("a100-80:30,a100-40:30,h100-96:20,h100-80:20")
+    try:
+        prog = batched.batched_program(
+            "mfi", SimConfig(cluster_spec=spec, seed=0), R, use_kernel=True
+        )
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            prog.events,
+        )
+        compiled = batched._simulate.lower(shapes, **prog.kwargs).compile()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    text = compiled.as_text()
+    body = re.findall(r"body=%?([\w.\-]+)", text)
+    assert body, "no while loop in the scan"
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    loop = "\n".join(line for name in body for line in comps.get(name, []))
+    calls = re.findall(r"%(\w+)\.\d+ = [^\n]*custom-call\(", loop)
+    assert calls.count("select_from_base") == len(spec.model_groups()) == 4
+    assert "fragscore" not in calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
