@@ -54,11 +54,9 @@ Heterogeneous fleets
     (e.g. the stylized H200-141GB) ride the same padded-width path.
 
 Replica state (fixed-capacity struct-of-arrays pytree)
-    * ``occ (M, S) int32`` — cluster occupancy bitmap (materialized only
-      when the Pallas-kernel scoring path needs it; otherwise ``base``
-      carries the full information);
     * ``base (M, N) float32`` — occupied-slice count per placement window
-      of each GPU's own model, ``occ @ W[midx]ᵀ``.  Window counts are
+      of each GPU's own model, ``occ @ W[midx]ᵀ`` for the occupancy bitmap
+      ``occ (M, S)``, which the carry never holds.  Window counts are
       *linear* in occupancy, so ``base`` is maintained incrementally (row
       add on commit, row subtract on release) and every fragmentation
       quantity — F(m), the full MFI ΔF table, feasibility — derives from
@@ -153,11 +151,12 @@ Parity guarantees vs the Python reference (``tests/test_batched_sim.py``,
   as ``run_many`` (seed ``cfg.seed + r * 9973``), so the demand-grid
   traces match the Python simulator to float tolerance on the same stream.
 
-On TPU, per-GPU fragmentation rescoring (the rows each drain/commit
-touches, which feed both MFI and the severity metric) routes through the
-Pallas ``fragscore`` kernel (``interpret=False``) — homogeneous specs only
-(the kernel bakes in one placement table); on CPU and on mixed fleets the
-``base``-derived pure-jnp scoring is used.
+Per-GPU fragmentation rescoring (the rows each drain/commit touches,
+which feed both MFI and the severity metric) is always the ``base``-derived
+pure-jnp :func:`_frag_from_base`, on every backend and fleet: the window
+counts hold everything F(m) needs, and every score is a sum of small
+integers in float32, so it equals the occupancy-based ``fragscore`` kernel
+bit for bit without a kernel launch or an occupancy plane in the carry.
 """
 
 from __future__ import annotations
@@ -464,9 +463,8 @@ def make_delta_fn(
     are static, so each launch sees one placement table with static
     shapes), scattered back into the padded ``(M, A)`` layout the
     masked-refinement select consumes.  This is how ``use_kernel`` works on
-    *mixed* fleets — the occupancy-based ``fragscore`` kernel still
-    requires a homogeneous spec (it bakes in one table), but the ΔF path
-    only needs per-group window counts.  ``interpret`` as in
+    *mixed* fleets: the ΔF path only needs per-group window counts.
+    ``interpret`` as in
     :func:`repro.kernels.interpret_mode`.
     """
     from repro.kernels.fragscore import fragscore as _k
@@ -1500,7 +1498,6 @@ def policy_select(
 
 
 class ReplicaState(NamedTuple):
-    occ: jax.Array        # (M, S) int32 — None when occupancy isn't tracked
     base: jax.Array       # (M, N) float32 — occ @ W[midx]ᵀ, kept incrementally
     free: jax.Array       # (M,) int32
     f: jax.Array          # (M,) float32 — per-GPU F score, kept incrementally
@@ -1612,7 +1609,6 @@ def _init_state(
     midx: jax.Array,
     ring_rows: int,
     ring_cols: int,
-    track_occ: bool,
     track_alloc: bool,
     wait_slots: int = 0,
     faulted: bool = False,
@@ -1628,7 +1624,6 @@ def _init_state(
     track_alloc = track_alloc or faulted
     zr = (lambda: jnp.zeros((ring_rows, ring_cols), jnp.int32)) if faulted else (lambda: None)
     return ReplicaState(
-        occ=jnp.zeros((num_gpus, s), jnp.int32) if track_occ else None,
         base=jnp.zeros((num_gpus, n), jnp.float32),
         free=tables.slices[midx].astype(jnp.int32),
         f=jnp.zeros((num_gpus,), jnp.float32),
@@ -1720,7 +1715,6 @@ class EngineCore:
     tables: SpecTables
     midx: jax.Array
     vg: jax.Array
-    frag_fn: Optional[object] = None
     delta_fn: Optional[object] = None
     select_fn: Optional[object] = None
     migrate_fn: Optional[object] = None
@@ -1742,30 +1736,22 @@ class EngineCore:
         drain = functools.partial(_drain_ring_row, onehot=self.drain_onehot)
         rel_gpu, _ = drain(st.ring_gpu, drain_row)  # (E,)
         rel_mask, ring_mask = drain(st.ring_mask, drain_row, ns)  # (E, S)
-        occ = None if st.occ is None else st.occ.at[rel_gpu].add(-rel_mask)
         rel_win = jnp.einsum(
             "es,ens->en", rel_mask.astype(jnp.float32), self.tables.W[self.midx[rel_gpu]]
         )  # (E, N) — window counts each release frees, per its GPU's model
         base = st.base.at[rel_gpu].add(-rel_win)
         free = st.free.at[rel_gpu].add(rel_mask.sum(axis=1))
-        f = self._rescore(st.f, rel_gpu, occ, base, free)  # exactly the touched rows
-        return st._replace(
-            occ=occ, base=base, free=free, f=f, ring_mask=ring_mask
-        )
+        f = self._rescore(st.f, rel_gpu, base, free)  # exactly the touched rows
+        return st._replace(base=base, free=free, f=f, ring_mask=ring_mask)
 
-    def _rescore(self, f, g, occ, base, free):
-        """``f`` with GPU ``g`` (one id, or a vector of ids) rescored: through
-        the Pallas kernel when it is routed in (occ is materialized then),
-        else from the window counts.  Runs under the ``rescore`` scope
+    def _rescore(self, f, g, base, free):
+        """``f`` with GPU ``g`` (one id, or a vector of ids) rescored from
+        the window counts.  Runs under the ``rescore`` scope
         (:func:`repro.obs.scope_map`)."""
         one = jnp.ndim(g) == 0
         rows = (lambda x: x[g][None]) if one else (lambda x: x[g])
         with jax.named_scope("rescore"):
-            new = (
-                self.frag_fn(rows(occ))
-                if self.frag_fn is not None
-                else _frag_from_base(rows(base), rows(free), self.metric, rows(self.vg))
-            )
+            new = _frag_from_base(rows(base), rows(free), self.metric, rows(self.vg))
             return f.at[g].set(new[0] if one else new)
 
     def _btable(self):
@@ -1798,17 +1784,13 @@ class EngineCore:
         rows, cols = st.ring_gpu.shape
         live = st.ring_mask.sum(axis=-1) > 0          # (K+2, E)
         evict = fail_v[st.ring_gpu] & live            # stale slots: live=False
-        fi = fail_v.astype(jnp.int32)
-        occ = None if st.occ is None else st.occ * (1 - fi)[:, None]
         base = jnp.where(fail_v[:, None], 0.0, st.base)
         free = jnp.where(
             fail_v, self.tables.slices[self.midx].astype(jnp.int32), st.free
         )
         f = jnp.where(fail_v, 0.0, st.f)
         ring_mask = st.ring_mask * (1 - evict.astype(jnp.int32))[:, :, None]
-        st = st._replace(
-            up=up, occ=occ, base=base, free=free, f=f, ring_mask=ring_mask
-        )
+        st = st._replace(up=up, base=base, free=free, f=f, ring_mask=ring_mask)
 
         ev_flat = evict.reshape(-1)                   # flat (row, col) order
         n_ev = ev_flat.sum().astype(jnp.int32)
@@ -1871,10 +1853,6 @@ class EngineCore:
         base = base.at[res.new_gpu].add(res.new_mwin * mf)
         free = st.free.at[res.vic_gpu].add(res.old_mask.sum() * mi)
         free = free.at[res.new_gpu].add(-res.new_mask.sum() * mi)
-        occ = st.occ
-        if occ is not None:
-            occ = occ.at[res.vic_gpu].add(-res.old_mask * mi)
-            occ = occ.at[res.new_gpu].add(res.new_mask * mi)
         rc = (res.vic_row, res.vic_col)
         ring_mask = st.ring_mask.at[rc].add((res.new_mask - res.old_mask) * mi)
         ring_gpu = st.ring_gpu.at[rc].set(
@@ -1884,7 +1862,7 @@ class EngineCore:
             jnp.where(res.mig, res.new_aidx, st.ring_aidx[rc])
         )
         st = st._replace(
-            occ=occ, base=base, free=free,
+            base=base, free=free,
             ring_gpu=ring_gpu, ring_mask=ring_mask, ring_aidx=ring_aidx,
         )
         gpu = jnp.where(res.mig, res.gpu, gpu)
@@ -1908,14 +1886,13 @@ class EngineCore:
         kg = midx[gpu_c]  # chosen GPU's model index
         mask = tables.profile_masks[kg, pid_c, aidx] * oki  # (S,)
         mwin = tables.maskwin[kg, pid_c, aidx] * oki.astype(jnp.float32)  # (N,)
-        occ = None if st.occ is None else st.occ.at[gpu_c].add(mask)
         base = st.base.at[gpu_c].add(mwin)
         free = st.free.at[gpu_c].add(-mask.sum())
-        f = self._rescore(st.f, gpu_c, occ, base, free)
+        f = self._rescore(st.f, gpu_c, base, free)
         if mig_res is not None:
             # rescore the victim's landing GPU too (its old GPU is gpu_c)
             g2 = jnp.where(mig_res.mig, mig_res.new_gpu, gpu_c)
-            f = self._rescore(f, g2, occ, base, free)
+            f = self._rescore(f, g2, base, free)
         rr = st.rr
         if self.spec.stateful_cursor:  # advance the cursor past the chosen GPU
             rr = jnp.where(ok, (gpu_c + 1) % midx.shape[0], rr).astype(jnp.int32)
@@ -1946,7 +1923,7 @@ class EngineCore:
             ring_ten = put_meta(ring_ten, ten_m)
             ring_eidx = put_meta(ring_eidx, eidx_m)
         return st._replace(
-            occ=occ, base=base, free=free, f=f, rr=rr,
+            base=base, free=free, f=f, rr=rr,
             ring_gpu=ring_gpu, ring_mask=ring_mask,
             ring_pid=ring_pid, ring_aidx=ring_aidx,
             ring_end=ring_end, ring_eidx=ring_eidx,
@@ -2218,22 +2195,18 @@ def _build_core(
         cspec = _default_spec(num_gpus)
         tables = spec_tables(cspec)
         midx = jnp.asarray(cspec.model_index)
-    frag_fn = delta_fn = select_fn = migrate_fn = None
+    delta_fn = select_fn = migrate_fn = None
     if use_kernel:
         # Pallas dispatch rules (`kernel_spec` is the static ClusterSpec):
-        # the occupancy-based `fragscore` rescore kernel needs one placement
-        # table, so it compiles in on homogeneous specs only (mixed fleets
-        # keep the base-derived rescoring); the fused `delta_from_base` ΔF
-        # kernel dispatches per model group and serves any fleet, for specs
-        # whose keys consume ΔF; specs that additionally declare
-        # argmin-fusability (`PolicySpec.fused_argmin`) lower the whole
-        # select stage — and, for defrag specs, both migrate refinements —
-        # to the fused `select_from_base` / `migrate_refine` kernels (the
-        # `(M, A)` score table stays in VMEM).  `kernel_lowering="delta"`
-        # keeps only the ΔF kernel.
+        # rescoring is base-derived on every fleet (`_rescore`); the fused
+        # `delta_from_base` ΔF kernel dispatches per model group and serves
+        # any fleet, for specs whose keys consume ΔF; specs that additionally
+        # declare argmin-fusability (`PolicySpec.fused_argmin`) lower the
+        # whole select stage — and, for defrag specs, both migrate
+        # refinements — to the fused `select_from_base` / `migrate_refine`
+        # kernels (the `(M, A)` score table stays in VMEM).
+        # `kernel_lowering="delta"` keeps only the ΔF kernel.
         kspec = kernel_spec if kernel_spec is not None else _default_spec(num_gpus)
-        if kspec.is_homogeneous:
-            frag_fn = make_frag_fn(metric, True, kspec.models[0])
         fused, delta = _select_kernels(pspec, proto)
         if delta:
             delta_fn = make_delta_fn(kspec, metric)
@@ -2244,7 +2217,7 @@ def _build_core(
     vg = tables.V[midx]  # (M, N) per-GPU window sizes, gathered once
     core = EngineCore(
         spec=pspec, protocol=proto, metric=metric, tables=tables,
-        midx=midx, vg=vg, frag_fn=frag_fn, delta_fn=delta_fn,
+        midx=midx, vg=vg, delta_fn=delta_fn,
         select_fn=select_fn, migrate_fn=migrate_fn,
         wait_patience=wait_patience, drain_onehot=ring_drain_onehot(replicas),
     )
@@ -2267,7 +2240,7 @@ def _broadcast_init(
         lambda x: jnp.broadcast_to(x, (runs,) + x.shape),
         _init_state(
             core.tables, core.midx, ring_rows, ring_cols,
-            track_occ=core.frag_fn is not None, track_alloc=core.spec.defrag,
+            track_alloc=core.spec.defrag,
             wait_slots=wait_slots if core.protocol.queued else 0,
             faulted=core.protocol.faulted,
         ),

@@ -230,6 +230,36 @@ class TestPerModelKernelParity:
                 np.testing.assert_array_equal(got, want)
 
 
+class TestRescoreFromBase:
+    """The batched engine rescores touched GPUs from the window counts
+    (``_frag_from_base``) on every fleet; on the paper's A100-80GB fleet that
+    equals the occupancy-based ``fragscore`` kernel and the jnp
+    ``make_frag_fn`` form bit for bit (every score is a sum of small
+    integers in float32)."""
+
+    @pytest.mark.parametrize("m", [1, 11, 100])
+    @pytest.mark.parametrize("metric", ["blocked", "partial"])
+    def test_base_form_equals_occupancy_forms(self, m, metric):
+        model = mig.A100_80GB
+        rng = np.random.default_rng(m)
+        fill = rng.random((m, 1))  # every density from empty to full
+        occ = (rng.random((m, model.num_mem_slices)) < fill).astype(np.int32)
+        w = model.placement_masks.astype(np.float32)
+        v = model.placement_mem.astype(np.float32)
+        base = occ.astype(np.float32) @ w.T
+        free = model.num_mem_slices - occ.sum(axis=1)
+        got = np.asarray(
+            batched._frag_from_base(
+                jnp.asarray(base), jnp.asarray(free, jnp.int32), metric,
+                jnp.broadcast_to(jnp.asarray(v), base.shape),
+            )
+        )
+        kernel = batched.make_frag_fn(metric, True, model, interpret=True)
+        for form in (kernel, batched.make_frag_fn(metric, False, model)):
+            np.testing.assert_array_equal(got, np.asarray(form(jnp.asarray(occ))))
+        assert got.dtype == np.float32
+
+
 # ---------------------------------------------------------------------------
 # Fused select / migrate kernels: ΔF + in-kernel lexicographic argmin
 # ---------------------------------------------------------------------------

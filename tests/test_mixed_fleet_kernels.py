@@ -2,9 +2,8 @@
 
 On a mixed fleet the engine's select stage makes one ``select_from_base``
 launch per device model (:func:`repro.sim.batched.make_select_fn`) and
-rescores touched GPUs from the window counts (``_frag_from_base``: the
-``fragscore`` kernel holds one placement table and compiles in on
-homogeneous fleets only).  Here the kernels run in interpret mode on the
+rescores touched GPUs from the window counts (``_frag_from_base``, as on
+every fleet).  Here the kernels run in interpret mode on the
 CPU, over the benchmark's ``mixed100`` fleet and over a fleet whose
 repeated model is split across entries (a model group's rows are then not
 contiguous), and every event must equal the host reference's decision and
@@ -34,7 +33,7 @@ def _trace(prog, use_kernel):
         midx=kwargs["midx"], tables=kwargs["tables"],
     )
     if use_kernel:  # the path under test: per-group select, base-derived rescoring
-        assert core.select_fn is not None and core.frag_fn is None
+        assert core.select_fn is not None
     _, trace = batched._simulate(jax.tree.map(jnp.asarray, prog.events), **kwargs)
     return jax.device_get(trace)
 

@@ -46,8 +46,7 @@ def _text(prog, scoped=True):
 
 @pytest.mark.parametrize("fleet", ["homogeneous", "mixed"])
 def test_scope_map_finds_rescore_and_dispatch(fleet):
-    """Rescoring is scoped on either fleet (the ``fragscore`` kernel where the
-    fleet is homogeneous, ``_frag_from_base`` on a mixed one); the mixed
+    """Rescoring (``_frag_from_base`` on either fleet) is scoped; the mixed
     fleet's per-group kernel launches are wrapped in ``dispatch``."""
     spec = None if fleet == "homogeneous" else SMALL_MIXED
     prog = _program(spec, use_kernel=fleet == "mixed")

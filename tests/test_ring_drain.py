@@ -22,15 +22,14 @@ from repro.sim import SimConfig, batched
 
 
 def _random_expire_inputs(core, rng, runs=6, rows=7, cols=5):
-    """A vmapped carry with random ring planes (defrag planes and occupancy
-    included), one drain row per replica — the trash row ``K + 1`` among
-    them — and ``new_slot`` false on some lanes."""
+    """A vmapped carry with random ring planes (defrag planes and window
+    counts included), one drain row per replica — the trash row ``K + 1``
+    among them — and ``new_slot`` false on some lanes."""
     st = batched._broadcast_init(core, runs, rows, cols, 0)
-    m, s = st.occ.shape[1:]
-    n = st.base.shape[-1]
+    m, n = st.base.shape[1:]
+    s = st.ring_mask.shape[-1]
     ri = lambda hi, shape: jnp.asarray(rng.integers(0, hi, shape), jnp.int32)  # noqa: E731
     st = st._replace(
-        occ=ri(3, (runs, m, s)),
         base=jnp.asarray(rng.integers(0, 5, (runs, m, n)), jnp.float32),
         free=ri(9, (runs, m)),
         ring_gpu=ri(m, (runs, rows, cols)),
@@ -86,8 +85,6 @@ def test_expire_stage_states_agree(form):
     core, _, _ = batched._build_core(
         policy="mfi-defrag", metric="blocked", num_gpus=6, use_kernel=False,
     )
-    # rescore from the occupancy, as the kernel path does, in jnp
-    core = dataclasses.replace(core, frag_fn=batched.make_frag_fn("blocked"))
     st, drain_row, new_slot = _random_expire_inputs(core, np.random.default_rng(11))
     one = jax.jit(core._stage_expire)
     want = [

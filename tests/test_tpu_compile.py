@@ -100,7 +100,8 @@ def _kernel_program(kernel, m, sharding):
         )
         return jax.vmap(fn), state[:3] + victims
     assert kernel == "fragscore", kernel
-    # the engine rescores the touched rows of each replica's occupancy
+    # occupancy rows of R replicas, as `ops.py` and `core/cluster.py` callers
+    # vmap it (the engine rescores from the window counts instead)
     fn = batched.make_frag_fn("blocked", True, mig.A100_80GB, interpret=False)
     s_ = int(tables.W.shape[-1])
     occ = jax.ShapeDtypeStruct((R, m, s_), jnp.int32, sharding=sharding)
@@ -189,9 +190,8 @@ def _compile_sharded(topo):
         batched._simulate.lower(shapes, **prog.kwargs).compile()
 
 
-def _loop_copies(text):
-    """``(name, shape)`` of every copy in the scan's ``while`` body and the
-    computations it calls (fusion bodies hold no copies of their own)."""
+def _computations(text):
+    """Each computation's name -> its lines, from a compiled module's text."""
     comps, cur = {}, None
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
@@ -199,6 +199,21 @@ def _loop_copies(text):
             cur = comps.setdefault(head.group(1), [])
         elif cur is not None:
             cur.append(line)
+    return comps
+
+
+def _loop_body(text):
+    """The text of the scan's ``while`` body computations."""
+    body = re.findall(r"body=%?([\w.\-]+)", text)
+    assert body, "no while loop in the scan"
+    comps = _computations(text)
+    return "\n".join(line for name in body for line in comps.get(name, []))
+
+
+def _loop_copies(text):
+    """``(name, shape)`` of every copy in the scan's ``while`` body and the
+    computations it calls (fusion bodies hold no copies of their own)."""
+    comps = _computations(text)
     todo = re.findall(r"body=%?([\w.\-]+)", text)
     seen = set()
     while todo:
@@ -278,18 +293,55 @@ def test_mixed_fleet_kernel_scan_compiles_for_v5e(one_chip, monkeypatch):
     finally:
         monkeypatch.undo()
         jax.clear_caches()
-    text = compiled.as_text()
-    body = re.findall(r"body=%?([\w.\-]+)", text)
-    assert body, "no while loop in the scan"
-    comps, cur = {}, None
-    for line in text.splitlines():
-        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
-        if head:
-            cur = comps.setdefault(head.group(1), [])
-        elif cur is not None:
-            cur.append(line)
-    loop = "\n".join(line for name in body for line in comps.get(name, []))
-    calls = re.findall(r"%(\w+)\.\d+ = [^\n]*custom-call\(", loop)
+    calls = re.findall(r"%(\w+)\.\d+ = [^\n]*custom-call\(", _loop_body(compiled.as_text()))
     assert calls.count("select_from_base") == len(spec.model_groups()) == 4
     assert "fragscore" not in calls
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+
+
+def test_paper_fleet_kernel_scan_rescores_from_window_counts(one_chip, monkeypatch):
+    """The steady mfi kernel scan on the paper's fleet (100 A100-80GB), 64
+    replicas, in the chip's drain form: one ``select_from_base`` launch in
+    the loop body, no ``fragscore`` kernel (rescoring reads the window
+    counts, as on a mixed fleet) and no ``(R, 100, 8)`` occupancy plane in
+    the loop's carry.  Mosaic and the drain form are steered as above."""
+    jax.clear_caches()
+    monkeypatch.setattr(fragscore, "interpret_mode", lambda interpret=None: False)
+    monkeypatch.setattr(
+        batched, "ring_drain_onehot",
+        lambda replicas: replicas >= batched.ONEHOT_DRAIN_REPLICAS,
+    )
+    try:
+        prog = batched.batched_program(
+            "mfi", SimConfig(num_gpus=100, seed=0), R, use_kernel=True
+        )
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            prog.events,
+        )
+        compiled = batched._simulate.lower(shapes, **prog.kwargs).compile()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\w+)\.\d+ = [^\n]*custom-call\(", _loop_body(text))
+    assert calls.count("select_from_base") == 1
+    assert "fragscore" not in calls
+    carries = re.findall(r"^\s*(?:ROOT )?%while[\w.]* = (\(.*\)) while\(", text, re.M)
+    assert carries, "no while op in the scan"
+    assert all(f"s32[{R},100,8]" not in c for c in carries)
+    assert all(f"f32[{R},100," in c for c in carries)  # the window counts stay
+
+
+def test_homogeneous_kernel_core_carries_no_occupancy():
+    """``_build_core(..., use_kernel=True)`` on the paper's fleet compiles no
+    occupancy-based rescoring in, and its initial carry has no ``occ``."""
+    core, _, _ = batched._build_core(
+        policy="mfi", metric="blocked", num_gpus=100, use_kernel=True,
+        kernel_spec=batched._default_spec(100),
+    )
+    assert core.select_fn is not None
+    assert not hasattr(core, "frag_fn")
+    st = batched._broadcast_init(core, 2, 5, 3, 0)
+    assert "occ" not in st._fields
+    assert st.base.shape == (2, 100, int(core.tables.W.shape[1]))
